@@ -75,6 +75,7 @@ def _inner(out_path: str, quick: bool) -> None:
     from repro import ckpt
     from repro import checkpoint as legacy
     from repro import optim
+    from repro import parallel as PX
     from repro.models.registry import build_model, get_config, \
         reduced_config
     from repro.train import init_sharded_zero1, make_bucket_layout
@@ -85,7 +86,7 @@ def _inner(out_path: str, quick: bool) -> None:
 
     rcfg = reduced_config(get_config(ARCH))
     model = build_model(rcfg, remat=False)
-    mesh = jax.make_mesh(MESH_SHAPE, ("pod", "data"))
+    mesh = PX.make_device_mesh(MESH_SHAPE, ("pod", "data"))
     params = model.init(jax.random.key(0))
     layout = make_bucket_layout(params, mesh, deterministic=True)
     state, opt_sh = init_sharded_zero1(optim.AdamWConfig(), params,
@@ -119,7 +120,7 @@ def _inner(out_path: str, quick: bool) -> None:
 
     wall["restore_sharded_s"] = timed(restore_same)
 
-    mesh2 = jax.make_mesh(RESHARD_SHAPE, ("pod", "data"))
+    mesh2 = PX.make_device_mesh(RESHARD_SHAPE, ("pod", "data"))
     params2 = model.init(jax.random.key(0))
     layout2 = make_bucket_layout(params2, mesh2, deterministic=True)
     assert layout2.bucket_sizes == layout.bucket_sizes
@@ -194,6 +195,7 @@ def main(quick: bool = False, out_path: str = DEFAULT_OUT) -> None:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{MESH_SHAPE[0] * MESH_SHAPE[1]}")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(REPO, "src"), REPO] +
         env.get("PYTHONPATH", "").split(os.pathsep))

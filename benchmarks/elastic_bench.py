@@ -161,6 +161,7 @@ def main(quick: bool = False, out_path: str = DEFAULT_OUT) -> None:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{N_DEVICES}")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(REPO, "src"), REPO] +
         env.get("PYTHONPATH", "").split(os.pathsep))

@@ -33,10 +33,11 @@ def run_gpu_model() -> dict:
 def run_tpu_hlo() -> str:
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp
+        from repro import parallel as PX
         from repro.collectives.hierarchical import make_hier_all_reduce
         from repro.analysis.hlo import analyze
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = PX.make_device_mesh((2, 4), ("pod", "data"))
         x = jax.ShapeDtypeStruct((8, 1 << 20), jnp.float32)
         rows = []
         for name, kw in (("flat", dict(flat=True)), ("hier", dict()),
@@ -50,6 +51,7 @@ def run_tpu_hlo() -> str:
         """)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = os.path.abspath(src)
     res = subprocess.run([sys.executable, "-c", code],

@@ -72,7 +72,7 @@ def _inner(quick: bool, out_path: str) -> None:
     from repro.train import make_bucket_layout, make_jitted_train_step
     from benchmarks.common import time_fn
 
-    mesh = jax.make_mesh(MESH_SHAPE, ("pod", "data"))
+    mesh = PX.make_device_mesh(MESH_SHAPE, ("pod", "data"))
     n_pod, n_data = MESH_SHAPE
 
     # ---------------- HLO accounting over the gradient pytree ------------
@@ -264,6 +264,7 @@ def main(quick: bool = False, out_path: str = DEFAULT_OUT) -> None:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{MESH_SHAPE[0] * MESH_SHAPE[1]}")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(REPO, "src"), REPO] +
         env.get("PYTHONPATH", "").split(os.pathsep))

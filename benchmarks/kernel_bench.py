@@ -52,16 +52,19 @@ def _cases():
     return [
         ("kernel_flash_attention",
          jax.jit(lambda q, k, v: flash_attention(
-             q, k, v, causal=True, block_q=64, block_k=64)),
+             q, k, v, causal=True, block_q=64, block_k=64,
+             interpret=True)),
          (q, k, v),
          lambda q, k, v: attention_ref(q, k, v, causal=True), ident),
         ("kernel_mamba_scan",
-         jax.jit(lambda *a: ssd(*a, chunk=64)),
+         jax.jit(lambda *a: ssd(*a, chunk=64, interpret=True)),
          (x, dt, A, Bm, Cm), ssd_ref, first),
         ("kernel_mlstm",
-         jax.jit(lambda *a: mlstm(*a, chunk=64)),
+         jax.jit(lambda *a: mlstm(*a, chunk=64, interpret=True)),
          (qm, km, vm, ir, fr), mlstm_ref, first),
-        ("kernel_rmsnorm", jax.jit(rmsnorm), (xr, wr), rmsnorm_ref, ident),
+        ("kernel_rmsnorm",
+         jax.jit(lambda x, w: rmsnorm(x, w, interpret=True)),
+         (xr, wr), rmsnorm_ref, ident),
     ]
 
 

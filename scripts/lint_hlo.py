@@ -81,6 +81,7 @@ def _split_budget(count, n_buckets):
 def run_matrix(args):
     import jax  # noqa: E402  (after the re-exec pinned the backend)
     from repro import optim, train
+    from repro import parallel as PX
     from repro.analysis import hlo, ir
     from repro.analysis.lint import (LintContext, budget_for,
                                      load_budgets, run_rules)
@@ -89,7 +90,7 @@ def run_matrix(args):
     from repro.sharding import make_rules
 
     assert jax.device_count() == N_DEVICES, jax.devices()
-    mesh = jax.make_mesh(MESH_SHAPE, ("pod", "data"))
+    mesh = PX.make_device_mesh(MESH_SHAPE, ("pod", "data"))
     # fsdp=False for every cell: the manual sync modes require
     # replicated params, and keeping the xla cell on the same rules
     # makes the budgets comparable across the matrix

@@ -156,6 +156,7 @@ class JobManager:
         env.update(self.env_extra)
         env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                             f"{s.size}")
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = (_SRC_DIR + os.pathsep
                              + env.get("PYTHONPATH", ""))
         env[JOB_ENV_VAR] = s.job_id

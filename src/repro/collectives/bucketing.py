@@ -51,6 +51,16 @@ from repro.collectives.hierarchical import (fast_reduce_scatter,
 
 DEFAULT_BUCKET_BYTES = 32 << 20          # 32 MiB of f32 per bucket
 
+# Compile options for a program that runs a bucketed schedule.  The bucket
+# plan already sets the size of every gradient collective; XLA's all-reduce
+# combiner ("all-reduce-combiner" in the TPU compiler, "cpu-all-reduce-
+# combiner" in the CPU one) would merge the independent per-bucket slow
+# hops into one tuple all-reduce that waits for every bucket, undoing the
+# plan and leaving the overlapped schedule nothing to overlap.
+NO_COMBINE_COMPILER_OPTIONS = {
+    "xla_disable_hlo_passes": "all-reduce-combiner,cpu-all-reduce-combiner",
+}
+
 
 @dataclasses.dataclass(frozen=True)
 class LeafSlot:

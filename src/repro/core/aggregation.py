@@ -13,12 +13,11 @@ from __future__ import annotations
 import math
 from typing import List, Optional, Sequence, Tuple
 
-import numpy as np
-
 import jax
 from jax.sharding import Mesh
 
 from repro.core.leaves import TpuLeaf
+from repro.parallel.mesh import make_device_mesh
 
 
 def round_robin_order(leaves: Sequence[TpuLeaf]) -> List[TpuLeaf]:
@@ -89,6 +88,5 @@ def leaves_to_mesh(leaves: Sequence[TpuLeaf], shape: Tuple[int, ...],
     if devices is None:
         devices = jax.devices()[:len(leaves)]
     index = {l: i for i, l in enumerate(leaves)}
-    dev_arr = np.array([devices[index[l]] for l in ordered],
-                       dtype=object).reshape(shape)
-    return Mesh(dev_arr, axis_names)
+    return make_device_mesh(shape, axis_names,
+                            devices=[devices[index[l]] for l in ordered])

@@ -44,13 +44,13 @@ import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import checkpoint as legacy_ckpt
 from repro import ckpt as ckpt_lib
 from repro import optim
+from repro import parallel as PX
 from repro.core.leaves import TpuLeaf
 from repro.data import DataConfig, SyntheticCorpus
 from repro.elastic import plan_elastic_remesh
@@ -59,7 +59,8 @@ from repro.faults.recovery import RecoveryReport, walk_committed
 from repro.faults.retry import NO_RETRY, RetryPolicy
 from repro.sharding import make_rules
 from repro.train import (EFState, init_sharded_zero1, init_slow_residuals,
-                         make_bucket_layout, make_jitted_train_step)
+                         make_bucket_layout, make_jitted_train_step,
+                         put_batch)
 
 
 def factorizations(n_devices: int) -> List[Tuple[int, int]]:
@@ -191,11 +192,21 @@ class ElasticRunResult:
 class _MeshCtx:
     shape: Tuple[int, int]
     mesh: Any
+    rules: Any
     layout: Any
     params: Any
     state: Any
     opt_shardings: Any
     step_fn: Any
+
+    @property
+    def shardings(self):
+        """Target shardings of ``(params, state)``: params replicated,
+        as the step returns them, so the first step on a mesh compiles
+        the same program as every later one."""
+        rep = NamedSharding(self.mesh, P())
+        return (jax.tree.map(lambda _: rep, self.params),
+                self.opt_shardings)
 
 
 def _dir_bytes(path: str) -> int:
@@ -259,9 +270,10 @@ class ElasticDriver:
 
     # ----------------------------------------------------------- setup
     def _setup(self, shape: Tuple[int, int], seed: int) -> _MeshCtx:
-        mesh = jax.make_mesh(tuple(shape), ("pod", "data"))
+        mesh = PX.make_device_mesh(tuple(shape), ("pod", "data"))
         rules = make_rules(mesh, fsdp=False)
-        params = self.model.init(jax.random.key(seed))
+        params = jax.device_put(self.model.init(jax.random.key(seed)),
+                                NamedSharding(mesh, P()))
         layout = make_bucket_layout(params, mesh,
                                     bucket_bytes=self.bucket_bytes,
                                     deterministic=True)
@@ -281,7 +293,7 @@ class ElasticDriver:
             bucket_bytes=self.bucket_bytes,
             slow_compress_bits=8 if self.ef else 0,
             slow_error_feedback=self.ef, deterministic_reduce=True)
-        return _MeshCtx(tuple(shape), mesh, layout, params, state,
+        return _MeshCtx(tuple(shape), mesh, rules, layout, params, state,
                         opt_sh, step_fn)
 
     @staticmethod
@@ -317,7 +329,7 @@ class ElasticDriver:
         t0 = time.perf_counter()
         rstep, (ctx.params, ctx.state) = ckpt_lib.restore_auto(
             path, (ctx.params, ctx.state),
-            shardings=(None, ctx.opt_shardings),
+            shardings=ctx.shardings,
             layout=ctx.layout if self.mode == "handoff" else None,
             retry=self.retry)
         self._resume_timing = {
@@ -380,12 +392,12 @@ class ElasticDriver:
         if self.mode == "handoff":
             rstep, (new.params, new.state) = ckpt_lib.restore_sharded(
                 plan.handoff.step_dir, (new.params, new.state),
-                shardings=(None, new.opt_shardings), layout=new.layout,
+                shardings=new.shardings, layout=new.layout,
                 retry=self.retry)
         else:
             rstep, (new.params, new.state) = legacy_ckpt.restore(
                 plan.handoff.step_dir, (new.params, new.state),
-                shardings=(None, new.opt_shardings))
+                shardings=new.shardings)
         restore_s = time.perf_counter() - t0
         assert rstep == step, (rstep, step)
         maybe_fire("driver.post_restore")
@@ -535,14 +547,13 @@ class ElasticDriver:
                 # periodic commit of the pre-step state; a handoff at
                 # this step already saved it
                 self._save(ctx, step)
-            batch = {k: jnp.asarray(v)
-                     for k, v in corpus.batch(step).items()}
+            batch = put_batch(corpus.batch(step), ctx.rules)
             if first_step:
                 maybe_fire("driver.first_step")
             t0 = time.perf_counter()
             with ctx.mesh:
-                ctx.params, ctx.state, metrics = ctx.step_fn(
-                    ctx.params, ctx.state, batch)
+                ctx.params, ctx.state, metrics = jax.block_until_ready(
+                    ctx.step_fn(ctx.params, ctx.state, batch))
             dt = time.perf_counter() - t0
             if first_step:
                 if measurements and measurements[-1].first_step_s == 0.0:

@@ -70,6 +70,7 @@ def run_child(code: str, *, plan: Optional[FaultPlan] = None,
     if n_devices > 0:
         child_env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={n_devices}")
+        child_env["JAX_PLATFORMS"] = "cpu"
     if plan is not None:
         child_env[ENV_VAR] = plan.to_env()
     else:

@@ -77,7 +77,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_bhsd(q, k, v, *, causal: bool, group: int,
                          block_q: int, block_k: int, softcap: float = 0.0,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """q: (BH, S, D); k/v: (BKv, S, D|Dv); group = H // Kv."""
     BH, S, D = q.shape
     Dv = v.shape[-1]

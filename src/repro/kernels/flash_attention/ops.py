@@ -23,7 +23,7 @@ def _pick_block(s: int, target: int) -> int:
                                              "softcap", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_k: int = 128, softcap: float = 0.0,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q: (B, S, H, D); k/v: (B, S, Kv, Dv).  Returns (B, S, H, Dv)."""
     B, S, H, D = q.shape
     Kv = k.shape[2]

@@ -6,6 +6,12 @@ paper's chunked algorithm: intra-chunk work is a pair of MXU matmuls
 ((Q,N)x(N,Q) and (Q,Q)x(Q,P)), the inter-chunk recurrence is a rank-N state
 update.  B/C tensors are grouped (G groups); the head->group mapping lives in
 the BlockSpec index maps so grouped heads re-read the same HBM block.
+
+Layout is head-major ((B,H,S,P), (B,H,S,1) dt, (B,G,S,N) B/C, transposed by
+``ops.ssd``) so every block's last two dims are (chunk, width), which the
+TPU tiling accepts; the per-head decay ``A`` is read as a scalar from SMEM.
+Mosaic has no cumsum, so the in-chunk prefix sum is a causal-masked
+reduction over a (Q,Q) tile.
 """
 from __future__ import annotations
 
@@ -26,22 +32,26 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0, :, 0].astype(jnp.float32)            # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)          # (Q,)
-    A = a_ref[0].astype(jnp.float32)                  # scalar
-    Bm = b_ref[0, :, 0].astype(jnp.float32)           # (Q, N)
-    Cm = c_ref[0, :, 0].astype(jnp.float32)           # (Q, N)
+    x = x_ref[0, 0].astype(jnp.float32)               # (Q, P)
+    dt = dt_ref[0, 0].astype(jnp.float32)             # (Q, 1)
+    A = a_ref[pl.program_id(1)]                       # f32 scalar (SMEM)
+    Bm = b_ref[0, 0].astype(jnp.float32)              # (Q, N)
+    Cm = c_ref[0, 0].astype(jnp.float32)              # (Q, N)
 
-    la = dt * A                                       # (Q,) log-decay
-    b_end = jnp.cumsum(la)                            # inclusive cumsum
-    xd = x * dt[:, None]
-
-    # intra-chunk decay matrix L[t,s] = exp(b_t - b_s) for t >= s
-    bt = b_end[:, None]
-    bs = b_end[None, :]
     ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    Lmat = jnp.where(ti >= si, jnp.exp(bt - bs), 0.0)
+    causal = ti >= si
+
+    def along_s(col):                                  # [t, s] = col[s]
+        return jnp.broadcast_to(col, (chunk, chunk)).T
+
+    la = dt * A                                       # (Q, 1) log-decay
+    b_end = jnp.sum(jnp.where(causal, along_s(la), 0.0), axis=1,
+                    keepdims=True)                    # inclusive cumsum
+    xd = x * dt
+
+    # intra-chunk decay matrix L[t,s] = exp(b_t - b_s) for t >= s
+    Lmat = jnp.where(causal, jnp.exp(b_end - along_s(b_end)), 0.0)
 
     CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q,Q)
@@ -52,15 +62,15 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref,
     state_prev = state_ref[...]                       # (P, N)
     y_off = jax.lax.dot_general(Cm, state_prev, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    y_off = y_off * jnp.exp(b_end)[:, None]
+    y_off = y_off * jnp.exp(b_end)
 
-    y_ref[0, :, 0] = (y_diag + y_off).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
     # state update: S' = exp(total) S + sum_s exp(total - b_s) x_s B_s^T
-    total = b_end[-1]
-    decay = jnp.exp(total - b_end)                    # (Q,)
+    total = b_end[chunk - 1:, :]                      # (1, 1)
+    decay = jnp.exp(total - b_end)                    # (Q, 1)
     chunk_state = jax.lax.dot_general(
-        xd * decay[:, None], Bm, (((0,), (0,)), ((), ())),
+        xd * decay, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)           # (P, N)
     state_ref[...] = state_prev * jnp.exp(total) + chunk_state
 
@@ -69,13 +79,13 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref,
         st_ref[0, 0] = state_ref[...]
 
 
-def ssd_pallas(x, dt, A, B, C, *, chunk: int, interpret: bool = True):
-    """x: (Bt,S,H,P); dt: (Bt,S,H); A: (H,); B/C: (Bt,S,G,N).
+def ssd_pallas(x, dt, A, B, C, *, chunk: int, interpret: bool = False):
+    """x: (Bt,H,S,P); dt: (Bt,H,S,1); A: (H,) f32; B/C: (Bt,G,S,N).
 
-    Returns (y (Bt,S,H,P), final_state (Bt,H,P,N) f32).
+    Returns (y (Bt,H,S,P), final_state (Bt,H,P,N) f32).
     """
-    Bt, S, H, P = x.shape
-    G, N = B.shape[2], B.shape[3]
+    Bt, H, S, P = x.shape
+    G, N = B.shape[1], B.shape[3]
     rep = H // G
     assert S % chunk == 0
     nc = S // chunk
@@ -85,20 +95,20 @@ def ssd_pallas(x, dt, A, B, C, *, chunk: int, interpret: bool = True):
         kernel,
         grid=(Bt, H, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
-            pl.BlockSpec((1, chunk, 1, N),
-                         lambda b, h, c, rep=rep: (b, c, h // rep, 0)),
-            pl.BlockSpec((1, chunk, 1, N),
-                         lambda b, h, c, rep=rep: (b, c, h // rep, 0)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, chunk, N),
+                         lambda b, h, c, rep=rep: (b, h // rep, c, 0)),
+            pl.BlockSpec((1, 1, chunk, N),
+                         lambda b, h, c, rep=rep: (b, h // rep, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bt, S, H, P), x.dtype),
+            jax.ShapeDtypeStruct((Bt, H, S, P), x.dtype),
             jax.ShapeDtypeStruct((Bt, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],

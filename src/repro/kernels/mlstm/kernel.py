@@ -5,6 +5,11 @@ scratch.  Math identical to ``repro.models.xlstm.mlstm_chunked`` (see the
 stabilized derivation there): per chunk one (Q,Q) score matmul + one (Q,Q)x
 (Q,Dv) value matmul + rank-Q state update — same MXU shape regime as flash
 attention, with exponential gate stabilization handled in f32 scratch.
+
+Layout is head-major ((B,H,S,D) and (B,H,S,1) gates, transposed by
+``ops.mlstm``) so every block's last two dims are (chunk, D) or (chunk, 1),
+which the TPU tiling accepts.  Mosaic has no cumsum/cummax, so the in-chunk
+prefix sum and prefix max are causal-masked reductions over a (Q,Q) tile.
 """
 from __future__ import annotations
 
@@ -33,87 +38,89 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, h_ref,
 
     D = head_dim
     scale = 1.0 / math.sqrt(D)
-    q = q_ref[0, :, 0].astype(jnp.float32) * scale    # (Q, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    ig = i_ref[0, :, 0].astype(jnp.float32)           # (Q,)
-    fg = f_ref[0, :, 0].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32) * scale       # (Q, D)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+    ig = i_ref[0, 0].astype(jnp.float32)              # (Q, 1)
+    fg = f_ref[0, 0].astype(jnp.float32)
+
+    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = ti >= si
+
+    def along_s(col):                                  # [t, s] = col[s]
+        return jnp.broadcast_to(col, (chunk, chunk)).T
 
     lf = jax.nn.log_sigmoid(fg)
-    b = jnp.cumsum(lf)                                 # (Q,)
-    a = ig - b
-    m0 = m_ref[0, 0]
-    rm = jnp.maximum(jax.lax.cummax(a, axis=0), m0)    # (Q,)
+    b = jnp.sum(jnp.where(causal, along_s(lf), 0.0), axis=1,
+                keepdims=True)                         # inclusive cumsum
+    a = ig - b                                         # (Q, 1)
+    a_s = along_s(a)
+    m0 = m_ref[...]                                    # (1, 1)
+    rm = jnp.maximum(jnp.max(jnp.where(causal, a_s, NEG_BIG), axis=1,
+                             keepdims=True), m0)       # (Q, 1) cummax
     m_t = b + rm
 
     qk = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q,Q)
-    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    w = jnp.where(ti >= si, jnp.exp(a[None, :] - rm[:, None]), 0.0)
+    w = jnp.where(causal, jnp.exp(a_s - rm), 0.0)
     scores = qk * w
 
     C0 = c_ref[...]                                    # (Dk, Dv)
     n0 = n_ref[...]                                    # (1, Dk)
-    inter_scale = jnp.exp(m0 - rm)                     # (Q,)
+    inter_scale = jnp.exp(m0 - rm)                     # (Q, 1)
     inter = jax.lax.dot_general(q, C0, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     num = (jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
-           + inter * inter_scale[:, None])
-    den = (jnp.sum(scores, axis=1)
-           + jnp.sum(q * n0, axis=1) * inter_scale)
-    h = num / jnp.maximum(jnp.abs(den), jnp.exp(-m_t))[:, None]
-    h_ref[0, :, 0] = h.astype(h_ref.dtype)
+           + inter * inter_scale)
+    den = (jnp.sum(scores, axis=1, keepdims=True)
+           + jnp.sum(q * n0, axis=1, keepdims=True) * inter_scale)
+    h = num / jnp.maximum(jnp.abs(den), jnp.exp(-m_t))
+    h_ref[0, 0] = h.astype(h_ref.dtype)
 
-    R = rm[-1]
-    decay_in = jnp.exp(a - R)                          # (Q,)
+    R = rm[chunk - 1:, :]                              # (1, 1)
+    kd = k * jnp.exp(a - R)                            # (Q, D)
     c_ref[...] = (C0 * jnp.exp(m0 - R)
-                  + jax.lax.dot_general(k * decay_in[:, None], v,
-                                        (((0,), (0,)), ((), ())),
+                  + jax.lax.dot_general(kd, v, (((0,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32))
-    n_ref[...] = (n0 * jnp.exp(m0 - R)
-                  + jnp.sum(k * decay_in[:, None], axis=0, keepdims=True))
-    m_ref[0, 0] = b[-1] + R
+    n_ref[...] = n0 * jnp.exp(m0 - R) + jnp.sum(kd, axis=0, keepdims=True)
+    m_ref[...] = b[chunk - 1:, :] + R
 
     @pl.when(ci == nc - 1)
     def _emit():
         co_ref[0, 0] = c_ref[...]
-        no_ref[0, 0] = n_ref[0]
-        mo_ref[0, 0] = m_ref[0, 0]
+        no_ref[0, 0] = n_ref[...]
+        mo_ref[0, 0] = m_ref[...]
 
 
 def mlstm_pallas(q, k, v, i_raw, f_raw, *, chunk: int,
-                 interpret: bool = True):
-    """q,k,v: (B,S,H,D); i_raw,f_raw: (B,S,H).
+                 interpret: bool = False):
+    """q,k,v: (B,H,S,D); i_raw,f_raw: (B,H,S,1).
 
-    Returns (h (B,S,H,D), (C (B,H,D,D), n (B,H,D), m (B,H)) f32).
+    Returns (h (B,H,S,D), (C (B,H,D,D), n (B,H,1,D), m (B,H,1,1)) f32).
     """
-    B, S, H, D = q.shape
+    B, H, S, D = q.shape
     assert S % chunk == 0
     nc = S // chunk
     kernel = functools.partial(_mlstm_kernel, chunk=chunk, head_dim=D)
+    seq = pl.BlockSpec((1, 1, chunk, D), lambda b, hh, c: (b, hh, c, 0))
+    gate = pl.BlockSpec((1, 1, chunk, 1), lambda b, hh, c: (b, hh, c, 0))
     h, C, n, m = pl.pallas_call(
         kernel,
         grid=(B, H, nc),
-        in_specs=[
-            pl.BlockSpec((1, chunk, 1, D), lambda b, hh, c: (b, c, hh, 0)),
-            pl.BlockSpec((1, chunk, 1, D), lambda b, hh, c: (b, c, hh, 0)),
-            pl.BlockSpec((1, chunk, 1, D), lambda b, hh, c: (b, c, hh, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, hh, c: (b, c, hh)),
-            pl.BlockSpec((1, chunk, 1), lambda b, hh, c: (b, c, hh)),
-        ],
+        in_specs=[seq, seq, seq, gate, gate],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, D), lambda b, hh, c: (b, c, hh, 0)),
+            seq,
             pl.BlockSpec((1, 1, D, D), lambda b, hh, c: (b, hh, 0, 0)),
-            pl.BlockSpec((1, 1, D), lambda b, hh, c: (b, hh, 0)),
-            pl.BlockSpec((1, 1), lambda b, hh, c: (b, hh)),
+            pl.BlockSpec((1, 1, 1, D), lambda b, hh, c: (b, hh, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1), lambda b, hh, c: (b, hh, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, D, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((D, D), jnp.float32),

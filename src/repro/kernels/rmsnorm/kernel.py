@@ -17,7 +17,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_pallas(x, w, *, eps: float = 1e-5, block_rows: int = 256,
-                   interpret: bool = True):
+                   interpret: bool = False):
     """x: (R, D); w: (D,)."""
     R, D = x.shape
     br = min(block_rows, R)

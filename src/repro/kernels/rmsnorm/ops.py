@@ -11,7 +11,7 @@ from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows",
                                              "interpret"))
 def rmsnorm(x, w, *, eps: float = 1e-5, block_rows: int = 256,
-            interpret: bool = True):
+            interpret: bool = False):
     """x: (..., D); w: (D,)."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])

@@ -1,7 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# ruff: noqa: E402  (the two lines above MUST precede any jax-touching import)
+# ruff: noqa: E402  (the lines above MUST precede any jax-touching import)
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this produces, into artifacts/dryrun/:
@@ -29,13 +30,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import optim
 from repro.analysis import hlo as hlo_analysis
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import SHAPES_BY_NAME, ShapeConfig, shape_applicable
 from repro.launch.mesh import make_production_mesh, production_rules
 from repro.models.registry import (ARCH_IDS, active_param_count,
                                    build_model, get_config, param_count)
 from repro.serve import make_prefill_step, make_serve_step
 from repro.sharding import MeshRules, tree_shardings, use_rules
-from repro.train import make_train_step
+from repro.train import make_jitted_train_step
 
 ARTIFACTS = os.path.join(os.path.dirname(__file__),
                          "..", "..", "..", "artifacts", "dryrun")
@@ -125,16 +127,10 @@ def build_cell(arch: str, shape_name: str, *, multi_pod: bool,
             seq_parallel=seq_parallel)
         opt_sh = tree_shardings(mesh, opt_rules, opt_shapes,
                                 _opt_axes(model, use_master))
-        step = make_train_step(model, ocfg, accum=accum, rules=rules,
-                               cross_pod_mode=cross_pod_mode)
-
-        def wrapped(params, opt_state, batch):
-            with use_rules(rules):
-                return step(params, opt_state, batch)
-
-        jitted = jax.jit(wrapped, donate_argnums=(0, 1),
-                         in_shardings=(param_sh, opt_sh, batch_sh),
-                         out_shardings=(param_sh, opt_sh, None))
+        jitted = make_jitted_train_step(
+            model, ocfg, accum=accum, rules=rules, param_shardings=param_sh,
+            opt_shardings=opt_sh, batch_sharding=batch_sh,
+            cross_pod_mode=cross_pod_mode)
         with mesh:
             lowered = jitted.lower(params_shapes, opt_shapes, in_specs)
         meta["model_flops"] = 6.0 * active_param_count(cfg) * shape.tokens
@@ -269,6 +265,7 @@ def main():
     ap.add_argument("--tag", default="")
     ap.add_argument("--out", default=os.path.abspath(ARTIFACTS))
     args = ap.parse_args()
+    enable_compile_cache()
 
     cells = []
     if args.all:

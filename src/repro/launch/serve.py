@@ -9,6 +9,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.models.registry import ARCH_IDS, build_model, get_config, \
     reduced_config
 from repro.serve import BatchedServer, Request
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

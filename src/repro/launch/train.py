@@ -1,16 +1,18 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> ...``
 
-On the CPU container this trains reduced configs on a single device; on a
-real TPU runtime the same entrypoint builds the production mesh and runs
-the sharded step (the dry-run proves those lower+compile).
+Trains reduced configs by default and ``--full-config`` at published
+widths; ``--data-parallel N`` builds a (data, model) mesh over the first
+N devices.  ``build_trainer`` is the one Trainer construction, shared
+with ``chip_smoke.py``.
 """
 from __future__ import annotations
 
 import argparse
-
-import jax
+from typing import Optional, Sequence
 
 from repro import optim
+from repro import parallel as PX
+from repro.compile_cache import enable_compile_cache
 from repro.data import DataConfig
 from repro.models.registry import ARCH_IDS, build_model, get_config, \
     reduced_config
@@ -36,9 +38,15 @@ def _parse_reconfig_schedule(spec: str):
     return events
 
 
-def _run_elastic(args, cfg, model) -> None:
-    """--reconfig-at path: the elastic preemption/repack driver."""
-    from repro.data import DataConfig
+def _model(args):
+    cfg = get_config(args.arch)
+    if not args.full_config:
+        cfg = reduced_config(cfg)
+    return cfg, build_model(cfg, remat=args.full_config)
+
+
+def build_elastic_driver(args: argparse.Namespace):
+    """The --reconfig-at path's ``(ElasticDriver, schedule)``."""
     from repro.elastic_driver import ElasticDriver
 
     if not args.data_parallel:
@@ -76,6 +84,7 @@ def _run_elastic(args, cfg, model) -> None:
                 f"reconfig step {e.step} is past the run "
                 f"(--steps {args.steps}); it would silently never fire")
     from repro.faults.retry import RetryPolicy
+    cfg, model = _model(args)
     drv = ElasticDriver(
         model,
         optim.AdamWConfig(peak_lr=args.lr, warmup_steps=20,
@@ -87,6 +96,12 @@ def _run_elastic(args, cfg, model) -> None:
         error_feedback=args.error_feedback,
         retry=RetryPolicy(max_retries=args.max_restore_retries),
         fallback_on_corrupt=args.fallback_on_corrupt)
+    return drv, schedule
+
+
+def _run_elastic(args) -> None:
+    """--reconfig-at path: the elastic preemption/repack driver."""
+    drv, schedule = build_elastic_driver(args)
     out = drv.run(args.steps, schedule,
                   initial_shape=(args.pod_parallel, args.data_parallel),
                   resume=args.resume)
@@ -106,7 +121,7 @@ def _run_elastic(args, cfg, model) -> None:
               f"{m.compile_s*1e3:.0f} ms, verified={m.verified}")
 
 
-def main():
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=ARCH_IDS)
     ap.add_argument("--steps", type=int, default=100)
@@ -115,6 +130,10 @@ def main():
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
+    ap.add_argument("--ckpt-every", type=int, default=50,
+                    help="commit a checkpoint after every N-th step")
+    ap.add_argument("--log-every", type=int, default=10,
+                    help="record loss and step time every N-th step")
     ap.add_argument("--full-config", action="store_true")
     ap.add_argument("--data-parallel", type=int, default=0,
                     help="devices for a (dp, mp) mesh; 0 = single device")
@@ -178,7 +197,7 @@ def main():
     ap.add_argument("--pod-parallel", type=int, default=1,
                     help="pod axis of the initial (pod, data) "
                          "factorization for --reconfig-at runs")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     # the recovery knobs act at restore time; with --no-resume there is
     # no restore, so accepting them would silently do nothing
@@ -193,32 +212,28 @@ def main():
                          "two")
     if args.max_restore_retries < 0:
         raise SystemExit("--max-restore-retries must be >= 0")
+    return args
 
-    cfg = get_config(args.arch)
-    if not args.full_config:
-        cfg = reduced_config(cfg)
-    model = build_model(cfg, remat=args.full_config)
 
-    if args.reconfig_at:
-        _run_elastic(args, cfg, model)
-        return
-
+def build_trainer(args: argparse.Namespace) -> Trainer:
+    """The Trainer ``main`` runs for ``args`` (without --reconfig-at)."""
+    cfg, model = _model(args)
     rules = None
     if args.data_parallel:
-        mesh = jax.make_mesh((args.data_parallel, args.model_parallel),
-                             ("data", "model"))
+        mesh = PX.make_device_mesh(
+            (args.data_parallel, args.model_parallel), ("data", "model"))
         # manual sync modes keep params replicated (train._check_manual_
         # sync_rules rejects FSDP rules), so build ZeRO-1-style rules
         from repro.train import MANUAL_SYNC_MODES
         rules = make_rules(
             mesh, fsdp=args.cross_pod_mode not in MANUAL_SYNC_MODES)
 
-    trainer = Trainer(
+    return Trainer(
         model,
         optim.AdamWConfig(peak_lr=args.lr, warmup_steps=20,
                           total_steps=args.steps),
-        TrainerConfig(n_steps=args.steps, ckpt_every=50,
-                      ckpt_dir=args.ckpt_dir, log_every=10,
+        TrainerConfig(n_steps=args.steps, ckpt_every=args.ckpt_every,
+                      ckpt_dir=args.ckpt_dir, log_every=args.log_every,
                       accum=args.accum,
                       cross_pod_mode=args.cross_pod_mode,
                       bucket_bytes=args.bucket_mb << 20,
@@ -230,7 +245,15 @@ def main():
         DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
                    global_batch=args.batch),
         rules=rules)
-    out = trainer.run(resume=args.resume)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+    enable_compile_cache()
+    if args.reconfig_at:
+        _run_elastic(args)
+        return
+    out = build_trainer(args).run(resume=args.resume)
     for h in out["history"]:
         print(f"step {h['step']:4d}  loss {h['loss']:.4f}  "
               f"{h['sec_per_step']*1e3:.0f} ms")

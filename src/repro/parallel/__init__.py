@@ -2,8 +2,8 @@
 
 Every SPMD primitive the repro uses lives behind this package:
 
-- :mod:`repro.parallel.compat` — version-portable ``shard_map`` (the only
-  place allowed to touch the raw jax implementations);
+- :mod:`repro.parallel.compat` — ``shard_map`` (the only place allowed
+  to touch ``jax.shard_map``) and manual-axis introspection;
 - :mod:`repro.parallel.mesh` — mesh construction + axis bookkeeping;
 - :mod:`repro.parallel.collectives` — named wrappers for the collectives
   (psum / ppermute / all_gather / ...);
@@ -17,8 +17,7 @@ from repro.parallel.collectives import (all_gather, all_gather_flat,
                                         axis_index, axis_size, pmax, pmean,
                                         ppermute, psum, psum_scatter,
                                         reduce_scatter_flat)
-from repro.parallel.compat import (SHARD_MAP_IMPL, manual_axes, shard_map,
-                                   static_axis_size)
+from repro.parallel.compat import manual_axes, shard_map
 from repro.parallel.mesh import (axes_size, axis_tuple, make_device_mesh,
                                  make_production_mesh)
 from repro.parallel.transport import (AXIS_TIER, TIERS, TransportTier,
@@ -26,7 +25,7 @@ from repro.parallel.transport import (AXIS_TIER, TIERS, TransportTier,
                                       tier_for_axis)
 
 __all__ = [
-    "SHARD_MAP_IMPL", "shard_map", "manual_axes", "static_axis_size",
+    "shard_map", "manual_axes",
     "axes_size", "axis_tuple", "make_device_mesh", "make_production_mesh",
     "psum", "pmean", "pmax", "ppermute", "all_gather", "psum_scatter",
     "axis_index", "axis_size", "reduce_scatter_flat", "all_gather_flat",

@@ -16,8 +16,6 @@ from typing import Sequence, Tuple, Union
 
 import jax
 
-from repro.parallel.compat import static_axis_size
-
 AxisName = Union[str, Tuple[str, ...], Sequence[str]]
 
 __all__ = ["psum", "pmean", "pmax", "ppermute", "all_gather",
@@ -66,7 +64,7 @@ def reduce_scatter_flat(x, axis: str):
     layouts guarantee this via their ``align``).  Inverse of
     :func:`all_gather_flat` up to the reduction.
     """
-    n = static_axis_size(axis)
+    n = axis_size(axis)
     shard = jax.lax.psum_scatter(x.reshape(n, -1), axis,
                                  scatter_dimension=0, tiled=False)
     return shard.reshape(-1)
@@ -85,5 +83,5 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str) -> int:
-    """Static size of a manual mesh axis (version-portable)."""
-    return static_axis_size(axis)
+    """Static size of a manual mesh axis."""
+    return jax.lax.axis_size(axis)

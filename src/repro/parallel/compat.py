@@ -1,109 +1,42 @@
-"""Version-portable SPMD primitives.
+"""SPMD primitives over ``jax.shard_map``.
 
-JAX has moved ``shard_map`` twice (``jax.experimental.shard_map.shard_map``
--> ``jax.shard_map``) and renamed two of its keywords along the way
-(``check_rep``/``auto`` -> ``check_vma``/``axis_names``).  Everything in
-this repro goes through :func:`shard_map` below, which presents the *new*
-keyword surface on every JAX version:
+Everything in this repro goes through :func:`shard_map` below:
 
     shard_map(f, mesh=mesh, in_specs=..., out_specs=...,
               check_vma=False, axis_names={"pod"})
-
-Resolution order (recorded in :data:`SHARD_MAP_IMPL` for tests/debugging):
-
-1. ``jax.shard_map``                       (JAX >= 0.6 style)
-2. ``jax.experimental.shard_map.shard_map``(JAX 0.4.x / 0.5.x); keywords
-   are translated: ``check_vma`` -> ``check_rep`` and ``axis_names`` ->
-   ``auto`` (the complement over the mesh axes).
-3. A documented fallback that raises ``NotImplementedError`` at *call*
-   time with upgrade guidance, so importing this module never fails even
-   on a JAX with no shard_map at all (analysis-only workflows still work).
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Set
+from typing import Callable, Optional, Set
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["shard_map", "SHARD_MAP_IMPL", "static_axis_size", "manual_axes"]
-
-
-_new_impl = getattr(jax, "shard_map", None)
-_old_impl = None
-if _new_impl is None:
-    try:
-        from jax.experimental.shard_map import shard_map as _old_impl
-    except ImportError:                     # pragma: no cover - ancient jax
-        _old_impl = None
-
-if _new_impl is not None:
-    SHARD_MAP_IMPL = "jax.shard_map"
-elif _old_impl is not None:
-    SHARD_MAP_IMPL = "jax.experimental.shard_map.shard_map"
-else:                                       # pragma: no cover - ancient jax
-    SHARD_MAP_IMPL = "unavailable"
+__all__ = ["shard_map", "manual_axes"]
 
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               check_vma: bool = True,
               axis_names: Optional[Set[str]] = None) -> Callable:
-    """Map ``f`` over shards of a mesh; new-style keyword surface.
+    """Map ``f`` over shards of a mesh.
 
     ``axis_names``: mesh axes mapped *manually* inside ``f`` (the rest
     stay automatic / visible to the partitioner).  ``None`` means all
-    mesh axes are manual, matching both upstream defaults.
+    mesh axes are manual, matching the upstream default.
     """
-    if _new_impl is not None:
-        kw: dict = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                        check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return _new_impl(f, **kw)
-    if _old_impl is not None:
-        auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-                if axis_names is not None else frozenset())
-        return _old_impl(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=check_vma, auto=auto)
-
-    def _unavailable(*a: Any, **k: Any):    # pragma: no cover - ancient jax
-        raise NotImplementedError(
-            "No shard_map implementation in this JAX "
-            f"({jax.__version__}); need jax>=0.4.26 for "
-            "jax.experimental.shard_map. Analytic/simulator paths work "
-            "without it; executable SPMD paths do not.")
-    return _unavailable
-
-
-def static_axis_size(axis) -> int:
-    """Size of a named mesh axis inside a shard_map body, as a static int.
-
-    ``jax.lax.axis_size`` only exists on newer JAX; on older versions
-    ``lax.psum(1, axis)`` is the canonical idiom (constant-folded to a
-    Python int, usable in reshapes).
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return jax.lax.psum(1, axis)
+    kw: dict = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                    check_vma=check_vma)
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)
 
 
 def manual_axes() -> frozenset:
     """Mesh axes that are Manual at the current trace point (i.e. we are
     inside a shard_map mapping them) — sharding constraints must not
-    mention them.  Returns an empty set on JAX versions without the
-    abstract-mesh introspection API (harmless: those versions reject the
-    constraint later only if a caller actually violates the rule, and all
-    in-repo callers drop manual axes explicitly via
-    ``sharding.without_axes``)."""
-    try:
-        get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-        if get_am is None:
-            return frozenset()
-        am = get_am()
-        if am is None or am.empty:
-            return frozenset()
-        from jax.sharding import AxisType
-        return frozenset(n for n in am.axis_names
-                         if am._name_to_type[n] == AxisType.Manual)
-    except Exception:                       # pragma: no cover - API drift
+    mention them."""
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return frozenset()
+    return frozenset(n for n, t in zip(am.axis_names, am.axis_types)
+                     if t == AxisType.Manual)

@@ -2,7 +2,7 @@
 
 Pure-jax layer under ``repro.sharding`` / ``repro.launch.mesh``: nothing
 here imports model or scheduler code, so SPMD plumbing has no cyclic
-dependencies and JAX-version quirks stay in one place.
+dependencies.
 """
 from __future__ import annotations
 
@@ -10,7 +10,7 @@ from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 Axes = Union[None, str, Tuple[str, ...]]
 
@@ -35,12 +35,21 @@ def axes_size(mesh: Optional[Mesh], axes: Axes) -> int:
 def make_device_mesh(shape: Sequence[int],
                      axis_names: Sequence[str],
                      *, devices=None) -> Mesh:
-    """``jax.make_mesh`` where available, manual reshape otherwise."""
-    mk = getattr(jax, "make_mesh", None)
-    if devices is None and mk is not None:
-        return mk(tuple(shape), tuple(axis_names))
-    devs = np.asarray(devices if devices is not None else jax.devices())
-    return Mesh(devs.reshape(tuple(shape)), tuple(axis_names))
+    """The repo's one mesh constructor; every axis is ``AxisType.Auto``.
+
+    ``jax.make_mesh`` alone types axes ``Explicit``, under which the
+    logical-rule sharding constraints (``sharding.shard``) are rejected.
+    With no ``devices`` the first ``prod(shape)`` devices are laid out in
+    ``jax.make_mesh``'s topology-aware order; given ``devices`` (exactly
+    ``prod(shape)`` of them) are laid out row-major in the order passed,
+    which is what placement policies (``core.aggregation``) rely on.
+    """
+    shape, axis_names = tuple(shape), tuple(axis_names)
+    axis_types = (AxisType.Auto,) * len(axis_names)
+    if devices is None:
+        return jax.make_mesh(shape, axis_names, axis_types=axis_types)
+    devs = np.asarray(devices, dtype=object).reshape(shape)
+    return Mesh(devs, axis_names, axis_types=axis_types)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -63,6 +63,17 @@ def use_rules(rules: Optional[MeshRules]):
         _current.reset(tok)
 
 
+def fit_spec(mesh: Mesh, shape: Tuple[int, ...], spec: P) -> P:
+    """``spec`` without the axes whose mesh extent does not divide the
+    dim they split (that dim stays replicated)."""
+    fixed = []
+    for dim, axes in zip(shape, tuple(spec) + (None,) * (
+            len(shape) - len(spec))):
+        n = _axes_size(mesh, axes)
+        fixed.append(axes if n == 1 or dim % n == 0 else None)
+    return P(*fixed)
+
+
 def shard(x, *logical: Optional[str]):
     """Annotate ``x`` with a sharding constraint for the active rules.
 
@@ -84,15 +95,7 @@ def shard(x, *logical: Optional[str]):
         return kept if len(kept) > 1 else (kept[0] if kept else None)
 
     spec = rules.to_pspec(tuple(logical))
-    spec = P(*(keep(ax) for ax in spec))
-    if rules.mesh is not None:
-        fixed = []
-        for dim, axes in zip(x.shape, tuple(spec) + (None,) * (
-                x.ndim - len(spec))):
-            n = _axes_size(rules.mesh, axes)
-            fixed.append(axes if (n > 1 and dim % n == 0) or n == 1
-                         else None)
-        spec = P(*fixed)
+    spec = fit_spec(rules.mesh, x.shape, P(*(keep(ax) for ax in spec)))
     if manual and all(ax is None for ax in spec):
         # every axis is manually mapped by the enclosing shard_map: the
         # constraint is vacuous per-rank, and an all-None constraint would

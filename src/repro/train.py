@@ -52,8 +52,8 @@ from repro.collectives import deterministic as det
 from repro.collectives.hierarchical import hier_all_reduce_mean
 from repro.data import DataConfig, Prefetcher, SyntheticCorpus
 from repro.elastic import HeartbeatMonitor, StragglerDetector
-from repro.sharding import (MeshRules, grad_sync_axes, use_rules,
-                            without_axes)
+from repro.sharding import (MeshRules, batch_axes, fit_spec,
+                            grad_sync_axes, use_rules, without_axes)
 
 MANUAL_SYNC_MODES = ("hier", "hier_bucketed", "hier_bucketed_zero1")
 BUCKETED_SYNC_MODES = ("hier_bucketed", "hier_bucketed_zero1")
@@ -559,7 +559,23 @@ def make_jitted_train_step(model, ocfg, *, accum, rules,
         kw["in_shardings"] = (param_shardings, opt_shardings,
                               batch_sharding)
         kw["out_shardings"] = (param_shardings, opt_shardings, None)
+    if cross_pod_mode in BUCKETED_SYNC_MODES:
+        kw["compiler_options"] = bucketing.NO_COMBINE_COMPILER_OPTIONS
     return jax.jit(wrapped, donate_argnums=(0, 1), **kw)
+
+
+def put_batch(batch: Dict[str, np.ndarray], rules: Optional[MeshRules]
+              ) -> Dict[str, jax.Array]:
+    """Host batch -> device arrays.  On a mesh the leading (batch) dim
+    is split over the rules' batch axes, so each device receives its
+    own rows instead of the whole batch landing on the default device
+    (replicated instead when the axes do not divide the batch)."""
+    if rules is None or rules.mesh is None:
+        return {k: jnp.asarray(v) for k, v in batch.items()}
+    spec = P(batch_axes(rules))
+    return {k: jax.device_put(v, NamedSharding(
+                rules.mesh, fit_spec(rules.mesh, np.shape(v), spec)))
+            for k, v in batch.items()}
 
 
 def wrap_ef_state(params, opt_state, opt_shardings, mesh, *,
@@ -831,9 +847,9 @@ class Trainer:
                     raise RuntimeError(f"injected failure at step {step}")
                 t0 = time.perf_counter()
                 _, batch = prefetch.next()
-                batch = {k: jnp.asarray(v) for k, v in batch.items()}
-                params, opt_state, metrics = self.step_fn(
-                    params, opt_state, batch)
+                params, opt_state, metrics = jax.block_until_ready(
+                    self.step_fn(params, opt_state,
+                                 put_batch(batch, self.rules)))
                 dt = time.perf_counter() - t0
                 self.heartbeat.beat(worker=0, t=time.time())
                 self.straggler.record(dt)

@@ -19,6 +19,7 @@ def run_multidevice(code: str, n_devices: int = 8,
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{n_devices}")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],

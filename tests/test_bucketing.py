@@ -169,6 +169,7 @@ def test_train_modes_equivalent_multidevice():
     """hier / hier_bucketed / hier_bucketed_zero1 match the xla step on a
     (pod, data) mesh, and the bucketed pair is bitwise-identical."""
     out = run_multidevice("""
+        from repro import parallel as PX
         import jax, jax.numpy as jnp, numpy as np
         from repro import optim
         from repro.models.registry import get_config, build_model, \\
@@ -178,7 +179,7 @@ def test_train_modes_equivalent_multidevice():
 
         cfg = reduced_config(get_config('llama3.2-1b'))
         model = build_model(cfg, remat=False)
-        mesh = jax.make_mesh((2, 2), ('pod', 'data'))
+        mesh = PX.make_device_mesh((2, 2), ('pod', 'data'))
         rules = make_rules(mesh, fsdp=False)
         rng = jax.random.key(1)
         batch = {'tokens': jax.random.randint(rng, (8, 32), 0,
@@ -226,6 +227,7 @@ def test_zero1_bitwise_parity_20_steps_multidevice():
     curves vs hier_bucketed over a 20-step run on a (pod, data) mesh,
     with the optimizer state sharded over the fast axis."""
     out = run_multidevice("""
+        from repro import parallel as PX
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro import optim
@@ -237,7 +239,7 @@ def test_zero1_bitwise_parity_20_steps_multidevice():
 
         cfg = reduced_config(get_config('llama3.2-1b'))
         model = build_model(cfg, remat=False)
-        mesh = jax.make_mesh((2, 2), ('pod', 'data'))
+        mesh = PX.make_device_mesh((2, 2), ('pod', 'data'))
         rules = make_rules(mesh, fsdp=False)
         corpus = SyntheticCorpus(DataConfig(vocab_size=cfg.vocab_size,
                                             seq_len=16, global_batch=8))

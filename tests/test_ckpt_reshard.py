@@ -31,6 +31,7 @@ def test_deterministic_reduce_rejected_outside_bucketed_modes():
 def test_reshard_continuation_bitwise_multidevice():
     """The PR-4 acceptance criterion, end to end."""
     out = run_multidevice("""
+        from repro import parallel as PX
         import os, tempfile
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -58,7 +59,7 @@ def test_reshard_continuation_bitwise_multidevice():
                        for k, v in corpus.batch(i).items()}
 
         def setup(shape, ef):
-            mesh = jax.make_mesh(shape, ('pod', 'data'))
+            mesh = PX.make_device_mesh(shape, ('pod', 'data'))
             rules = make_rules(mesh, fsdp=False)
             p = model.init(jax.random.key(0))
             layout = make_bucket_layout(p, mesh, bucket_bytes=bb,

@@ -17,6 +17,7 @@ import pytest
 
 from repro import checkpoint as legacy
 from repro import ckpt, optim
+from repro import parallel as PX
 from repro.core.leaves import TpuSliceTopology
 from repro.elastic import plan_elastic_remesh
 
@@ -111,7 +112,7 @@ def test_lost_shard_entries_detected(tmp_path):
     multi-host save missing one host) must refuse, not zero-fill."""
     import json
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = PX.make_device_mesh((1,), ("data",))
     arr = jax.device_put(jnp.arange(16.0), NamedSharding(mesh, P("data")))
     # force a 2-shard manifest by hand-splitting a replicated save
     sdir = ckpt.step_dir(str(tmp_path), 1)

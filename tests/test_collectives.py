@@ -68,10 +68,11 @@ def test_tpu_collective_two_tier():
 
 def test_hierarchical_allreduce_correct_multidevice():
     out = run_multidevice("""
+        from repro import parallel as PX
         import jax, jax.numpy as jnp, numpy as np
         from repro.collectives.hierarchical import make_hier_all_reduce
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = PX.make_device_mesh((2, 4), ("pod", "data"))
         x = jnp.arange(8 * 33, dtype=jnp.float32).reshape(8, 33)
         xs = jax.device_put(x, NamedSharding(mesh, P(("pod", "data"))))
         want = np.broadcast_to(np.asarray(x).reshape(8, 33).mean(0), (33,))
@@ -97,6 +98,7 @@ def test_hierarchical_allreduce_correct_multidevice():
 def test_moe_sharded_matches_single_device():
     """EP shard_map MoE == single-shard MoE on identical inputs."""
     out = run_multidevice("""
+        from repro import parallel as PX
         import jax, jax.numpy as jnp, numpy as np
         from repro.models.registry import get_config, reduced_config
         from repro.models import ffn as F
@@ -104,7 +106,7 @@ def test_moe_sharded_matches_single_device():
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         cfg = reduced_config(get_config("qwen2-moe-a2.7b"))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = PX.make_device_mesh((2, 4), ("data", "model"))
         rules = make_rules(mesh)
         key = jax.random.key(0)
         p = F.moe_init(key, cfg)

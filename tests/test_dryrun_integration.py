@@ -7,14 +7,15 @@ from tests.conftest import run_multidevice
 
 def test_dryrun_cell_on_small_mesh():
     out = run_multidevice("""
+        from repro import parallel as PX
         import os, json, tempfile
         # shrink the production mesh so the cell fits 8 fake devices
         import repro.launch.mesh as M
         import jax
         def small_mesh(*, multi_pod=False):
             if multi_pod:
-                return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-            return jax.make_mesh((2, 4), ("data", "model"))
+                return PX.make_device_mesh((2, 2, 2), ("pod", "data", "model"))
+            return PX.make_device_mesh((2, 4), ("data", "model"))
         M.make_production_mesh = small_mesh
         import repro.launch.dryrun as D
         D.make_production_mesh = small_mesh

@@ -88,9 +88,10 @@ def test_leaf_mesh_and_elastic_restore_multidevice():
 
 def test_gpipe_matches_sequential_multidevice():
     out = run_multidevice("""
+        from repro import parallel as PX
         import jax, jax.numpy as jnp, numpy as np
         from repro.pipeline import gpipe_forward
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = PX.make_device_mesh((4,), ("stage",))
         S, D, n_micro, mb = 4, 16, 6, 2
         ks = jax.random.split(jax.random.key(0), 2)
         w = jax.random.normal(ks[0], (S, D, D)) * 0.3
@@ -112,12 +113,13 @@ def test_gpipe_matches_sequential_multidevice():
 
 def test_flash_decode_sharded_matches_dense_multidevice():
     out = run_multidevice("""
+        from repro import parallel as PX
         import jax, jax.numpy as jnp, numpy as np
         from repro.models.attention import sharded_decode_attention
         from repro.models.layers import decode_attention
         from repro.sharding import make_rules, use_rules
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = PX.make_device_mesh((2, 4), ("data", "model"))
         rules = make_rules(mesh, seq_shard=True)
         B, S, H, Kv, Dh = 2, 64, 4, 2, 16
         ks = jax.random.split(jax.random.key(0), 3)

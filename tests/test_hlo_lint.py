@@ -454,13 +454,14 @@ ENTRY %main (p0: f32[8]) -> f32[8] {
 
 def test_compressed_mode_raises_not_implemented_multipod():
     out = run_multidevice("""
+        from repro import parallel as PX
         import jax
         from repro import optim
         from repro.models.registry import build_model, get_config, \\
             reduced_config
         from repro.sharding import make_rules
         from repro.train import make_train_step
-        mesh = jax.make_mesh((2, 2), ("pod", "data"))
+        mesh = PX.make_device_mesh((2, 2), ("pod", "data"))
         rules = make_rules(mesh, fsdp=False)
         model = build_model(reduced_config(get_config("llama3.2-1b")),
                             remat=False)

@@ -38,7 +38,8 @@ def test_flash_attention_sweep(S, H, Kv, D, causal, dtype):
     q = jax.random.normal(ks[0], (B, S, H, D), dtype)
     k = jax.random.normal(ks[1], (B, S, Kv, D), dtype)
     v = jax.random.normal(ks[2], (B, S, Kv, D), dtype)
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+                          interpret=True)
     ref = attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
@@ -50,7 +51,7 @@ def test_flash_attention_softcap():
     k = jax.random.normal(ks[1], (1, 128, 2, 32))
     v = jax.random.normal(ks[2], (1, 128, 2, 32))
     out = flash_attention(q, k, v, causal=True, softcap=20.0,
-                          block_q=64, block_k=64)
+                          block_q=64, block_k=64, interpret=True)
     ref = attention_ref(q, k, v, causal=True, softcap=20.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -64,7 +65,8 @@ def test_flash_attention_block_invariance(bq, bk):
     q = jax.random.normal(ks[0], (1, 128, 2, 32))
     k = jax.random.normal(ks[1], (1, 128, 2, 32))
     v = jax.random.normal(ks[2], (1, 128, 2, 32))
-    a = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+    a = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                        interpret=True)
     b = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=3e-5, atol=3e-5)
@@ -85,7 +87,7 @@ def test_ssd_kernel_sweep(T, H, P, G, N, chunk, dtype):
     A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.5)
     Bm = jax.random.normal(ks[3], (B, T, G, N), dtype)
     Cm = jax.random.normal(ks[4], (B, T, G, N), dtype)
-    y, s = ssd(x, dt, A, Bm, Cm, chunk=chunk)
+    y, s = ssd(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     yr, sr = ssd_ref(x, dt, A, Bm, Cm)
     tol = dict(rtol=4e-2, atol=4e-2) if dtype == jnp.bfloat16 \
         else dict(rtol=2e-4, atol=2e-4)
@@ -108,7 +110,8 @@ def test_mlstm_kernel_sweep(T, H, D, chunk):
     v = jax.random.normal(ks[2], (B, T, H, D))
     i_raw = jax.random.normal(ks[3], (B, T, H)) * 2
     f_raw = jax.random.normal(ks[4], (B, T, H)) * 2 + 3
-    h, (C, n, m) = mlstm(q, k, v, i_raw, f_raw, chunk=chunk)
+    h, (C, n, m) = mlstm(q, k, v, i_raw, f_raw, chunk=chunk,
+                         interpret=True)
     hr, (Cr, nr, mr) = mlstm_ref(q, k, v, i_raw, f_raw)
     np.testing.assert_allclose(np.asarray(h), np.asarray(hr),
                                rtol=5e-3, atol=5e-3)
@@ -124,7 +127,7 @@ def test_rmsnorm_sweep(R, D, dtype):
     ks = jax.random.split(jax.random.key(5), 2)
     x = jax.random.normal(ks[0], (R, D), dtype)
     w = jax.random.normal(ks[1], (D,), jnp.float32)
-    out = rmsnorm(x, w)
+    out = rmsnorm(x, w, interpret=True)
     ref = rmsnorm_ref(x, w)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -143,5 +146,5 @@ def test_mlstm_gate_stability_property(logf, logi):
     v = jax.random.normal(ks[2], (B, T, H, D))
     i_raw = jnp.full((B, T, H), logi)
     f_raw = jnp.full((B, T, H), logf)
-    h, _ = mlstm(q, k, v, i_raw, f_raw, chunk=16)
+    h, _ = mlstm(q, k, v, i_raw, f_raw, chunk=16, interpret=True)
     assert bool(jnp.all(jnp.isfinite(h)))

@@ -41,6 +41,7 @@ def test_overlap_bitwise_parity_10_steps_multidevice():
     the pipelined-with-residuals schedule and the zero1 EFState specs).
     """
     out = run_multidevice("""
+        from repro import parallel as PX
         import jax, jax.numpy as jnp, numpy as np
         from repro import optim
         from repro.data import DataConfig, SyntheticCorpus
@@ -53,7 +54,7 @@ def test_overlap_bitwise_parity_10_steps_multidevice():
 
         cfg = reduced_config(get_config('llama3.2-1b'))
         model = build_model(cfg, remat=False)
-        mesh = jax.make_mesh((2, 2), ('pod', 'data'))
+        mesh = PX.make_device_mesh((2, 2), ('pod', 'data'))
         rules = make_rules(mesh, fsdp=False)
         corpus = SyntheticCorpus(DataConfig(vocab_size=cfg.vocab_size,
                                             seq_len=16, global_batch=8))
@@ -135,7 +136,7 @@ def test_overlap_degenerate_noop_multidevice():
                                  total_steps=10)
 
         # (2,2) mesh, one giant bucket: pipeline degenerates to serial
-        mesh = jax.make_mesh((2, 2), ('pod', 'data'))
+        mesh = PX.make_device_mesh((2, 2), ('pod', 'data'))
         rules = make_rules(mesh, fsdp=False)
         losses = {}
         for overlap in (False, True):
@@ -184,7 +185,7 @@ def test_overlap_hlo_slow_collectives_independent_multidevice():
         from repro.analysis.hlo import slow_collective_chains
         from repro.collectives import bucketing as BK
 
-        mesh = jax.make_mesh((2, 2), ('pod', 'data'))
+        mesh = PX.make_device_mesh((2, 2), ('pod', 'data'))
         grads = {f't{i}': jax.ShapeDtypeStruct((256,), jnp.float32)
                  for i in range(6)}
         layout = BK.plan_buckets(grads, bucket_bytes=2048, align=2)
@@ -199,10 +200,12 @@ def test_overlap_hlo_slow_collectives_independent_multidevice():
                                              dtype=jnp.float32)
 
         specs = jax.tree.map(lambda _: P(), grads)
+        # compiled as the bucketed train step is compiled
         txt = jax.jit(PX.shard_map(
             fn, mesh=mesh, in_specs=(specs,), out_specs=specs,
             check_vma=False, axis_names={'pod', 'data'},
-        )).lower(grads).compile().as_text()
+        ), compiler_options=BK.NO_COMBINE_COMPILER_OPTIONS,
+        ).lower(grads).compile().as_text()
         chain = slow_collective_chains(txt, chips_per_pod=2)
         assert chain.n_slow == layout.n_buckets, chain
         assert chain.independent, chain.dependent_pairs
@@ -215,6 +218,7 @@ def test_int8_error_feedback_converges_closer_multidevice():
     """int8 + error feedback tracks the uncompressed loss curve strictly
     closer than int8 alone (summed |deviation| over 15 steps)."""
     out = run_multidevice("""
+        from repro import parallel as PX
         import jax, jax.numpy as jnp, numpy as np
         from repro import optim
         from repro.data import DataConfig, SyntheticCorpus
@@ -226,7 +230,7 @@ def test_int8_error_feedback_converges_closer_multidevice():
 
         cfg = reduced_config(get_config('llama3.2-1b'))
         model = build_model(cfg, remat=False)
-        mesh = jax.make_mesh((2, 2), ('pod', 'data'))
+        mesh = PX.make_device_mesh((2, 2), ('pod', 'data'))
         rules = make_rules(mesh, fsdp=False)
         corpus = SyntheticCorpus(DataConfig(vocab_size=cfg.vocab_size,
                                             seq_len=16, global_batch=8))

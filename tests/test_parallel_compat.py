@@ -8,10 +8,24 @@ from tests.conftest import run_multidevice
 
 
 def test_shard_map_resolves_on_this_jax():
-    assert PX.SHARD_MAP_IMPL in (
-        "jax.shard_map", "jax.experimental.shard_map.shard_map"), (
-        f"no usable shard_map on jax {jax.__version__}: "
-        f"{PX.SHARD_MAP_IMPL}")
+    """``PX.shard_map`` is ``jax.shard_map``: ``axis_names`` selects the
+    manual axes, which ``manual_axes`` reports inside the body only."""
+    from jax.sharding import AxisType, PartitionSpec as P
+    mesh = PX.make_device_mesh((1, 1), ("pod", "data"),
+                               devices=jax.devices()[:1])
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    seen = {}
+
+    def body(x):
+        seen["manual"] = PX.manual_axes()
+        return x + PX.axis_size("pod")
+
+    out = jax.jit(PX.shard_map(body, mesh=mesh, in_specs=P(),
+                               out_specs=P(), check_vma=False,
+                               axis_names={"pod"}))(jnp.zeros(2))
+    np.testing.assert_array_equal(np.asarray(out), [1.0, 1.0])
+    assert seen["manual"] == frozenset({"pod"})
+    assert PX.manual_axes() == frozenset()
 
 
 def test_shard_map_single_device_identity():

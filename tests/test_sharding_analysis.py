@@ -36,10 +36,11 @@ def test_hlo_analysis_counts_while_trip():
 
 def test_hlo_analysis_collectives_multidevice():
     out = run_multidevice("""
+        from repro import parallel as PX
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.analysis import hlo as H
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = PX.make_device_mesh((8,), ("d",))
         def f(x):
             return jax.lax.with_sharding_constraint(
                 x.sum(axis=0, keepdims=True), NamedSharding(mesh, P()))
@@ -212,9 +213,10 @@ def test_rules_divisibility_dropping():
     """Non-dividing dims silently stay replicated (whisper's 6 heads on a
     16-way axis)."""
     out = run_multidevice("""
+        from repro import parallel as PX
         import jax, jax.numpy as jnp
         from repro.sharding import make_rules, use_rules, shard
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = PX.make_device_mesh((2, 4), ("data", "model"))
         rules = make_rules(mesh)
         with mesh:
             with use_rules(rules):

@@ -1,0 +1,117 @@
+"""Ahead-of-time compiles for a described TPU v5e: the Pallas kernels at
+real widths, and the deterministic gradient reduce on a (2, 2) mesh.
+
+Nothing runs: the TPU compiler lowers each kernel at real widths for a
+chip that is described, not attached, and refuses what the chip would
+refuse (block shapes off the (8, 128) tiling, unsupported primitives,
+too much VMEM).  The topology is described only inside the module
+fixture below, so importing this file never loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.mamba_scan.ops import ssd
+from repro.kernels.mlstm.ops import mlstm
+from repro.kernels.rmsnorm.ops import rmsnorm
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache off meanwhile
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_text(fn, shapes, sharding) -> str:
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.mark.parametrize("H,Kv,D", [
+    (32, 8, 64),       # llama3.2-1b attention widths
+    (32, 2, 128),      # glm4-9b attention widths
+])
+def test_flash_attention_compiles_for_v5e(one_chip, H, Kv, D):
+    S = 2048
+    txt = _compile_text(
+        lambda q, k, v: flash_attention(q, k, v, causal=True),
+        [((1, S, H, D), BF16), ((1, S, Kv, D), BF16),
+         ((1, S, Kv, D), BF16)], one_chip)
+    assert "tpu_custom_call" in txt
+
+
+def test_mlstm_compiles_for_v5e(one_chip):
+    # xlstm-125m: d_model 768 -> 2x up-projection 1536 over 4 heads
+    B, S, H, D = 1, 2048, 4, 384
+    txt = _compile_text(
+        lambda q, k, v, i, f: mlstm(q, k, v, i, f, chunk=256),
+        [((B, S, H, D), BF16)] * 3 + [((B, S, H), F32)] * 2, one_chip)
+    assert "tpu_custom_call" in txt
+
+
+def test_ssd_compiles_for_v5e(one_chip):
+    # zamba2-1.2b: d_inner 4096 over head_dim 64, d_state 64, one group
+    B, S, H, P, G, N = 1, 2048, 64, 64, 1, 64
+    txt = _compile_text(
+        lambda x, dt, A, Bm, Cm: ssd(x, dt, A, Bm, Cm, chunk=256),
+        [((B, S, H, P), BF16), ((B, S, H), F32), ((H,), F32),
+         ((B, S, G, N), BF16), ((B, S, G, N), BF16)], one_chip)
+    assert "tpu_custom_call" in txt
+
+
+def test_rmsnorm_compiles_for_v5e(one_chip):
+    txt = _compile_text(lambda x, w: rmsnorm(x, w),
+                        [((4096, 2048), BF16), ((2048,), F32)], one_chip)
+    assert "tpu_custom_call" in txt
+
+
+def test_det_reduce_compiles_small_for_v5e(topo):
+    """The deterministic bucket reduce on a (2, 2) mesh: its program must
+    not grow with the bucket size (gathering an (R, C) stack and cutting
+    rows out of it compiled to ~50 MB of code per 16 MiB of buckets)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro import parallel as PX
+    from repro.collectives.deterministic import det_reduce_bucket_full
+
+    mesh = PX.make_device_mesh((2, 2), ("pod", "data"),
+                               devices=topo.devices[:4])
+    specs = (P(), P())
+
+    def body(buckets):
+        return det_reduce_bucket_full(buckets, sync_axes=("pod", "data"))[0]
+
+    fn = PX.shard_map(body, mesh=mesh, in_specs=(specs,), out_specs=specs,
+                      check_vma=False, axis_names={"pod", "data"})
+    rep = NamedSharding(mesh, P())
+    buckets = tuple(jax.ShapeDtypeStruct((2 << 20,), F32, sharding=rep)
+                    for _ in specs)
+    compiled = jax.jit(fn).lower(buckets).compile()
+    assert compiled.memory_analysis().generated_code_size_in_bytes < 4 << 20
+    assert "all-gather" in compiled.as_text()

@@ -13,6 +13,7 @@ from repro.data import DataConfig, Prefetcher, SyntheticCorpus
 from repro.models.registry import build_model, get_config, reduced_config
 from repro.train import (Trainer, TrainerConfig, make_jitted_train_step,
                          make_loss_and_grad)
+from tests.conftest import run_multidevice
 
 
 @pytest.fixture()
@@ -103,6 +104,36 @@ def test_trainer_checkpoint_restart(tmp_path, small_model):
     t2 = Trainer(model, ocfg, tcfg2, dcfg)
     out2 = t2.run(resume=True)
     assert out2["history"][0]["step"] == 6
+
+
+@pytest.mark.parametrize("batch,rows", [(6, [2, 2, 2]), (8, [8, 8, 8])])
+def test_trainer_data_parallel_batch_rows_multidevice(tmp_path, batch,
+                                                      rows):
+    """``--data-parallel 3``: each device gets its own rows of a batch
+    that 3 divides, and the whole of one that it does not."""
+    out = run_multidevice(f"""
+        import numpy as np
+        from repro.data import SyntheticCorpus
+        from repro.launch import train as launch_train
+        from repro.train import put_batch
+
+        args = launch_train.parse_args([
+            '--arch', 'llama3.2-1b', '--data-parallel', '3',
+            '--batch', '{batch}', '--seq', '16', '--steps', '2',
+            '--ckpt-dir', {str(tmp_path)!r}, '--ckpt-every', '2',
+            '--log-every', '1', '--no-resume'])
+        trainer = launch_train.build_trainer(args)
+        b = put_batch(SyntheticCorpus(trainer.data_cfg).batch(0),
+                      trainer.rules)
+        rows = sorted(s.data.shape[0]
+                      for s in b['tokens'].addressable_shards)
+        assert rows == {rows!r}, rows
+        hist = trainer.run(resume=False)['history']
+        assert [h['step'] for h in hist] == [0, 1], hist
+        assert all(np.isfinite(h['loss']) for h in hist), hist
+        print('BATCH_ROWS_OK')
+        """, n_devices=3)
+    assert "BATCH_ROWS_OK" in out
 
 
 def test_failure_injection_then_recovery(tmp_path, small_model):
