@@ -26,7 +26,10 @@ the continued run is *bitwise identical* to an uninterrupted run — the
 PR-4 invariant, asserted at every handoff (``verify=True`` additionally
 checks the restored state equals the saved state bit-for-bit).
 
-Every phase's wallclock is measured (:class:`HandoffMeasurement`), so
+Every phase is a ``repro.tracing`` span (``handoff.save``,
+``handoff.setup``, ``handoff.restore``, ``handoff.verify``, and
+``train.step`` per step), and its wallclock is read from that span
+(:class:`HandoffMeasurement`), so
 :meth:`repro.core.jct_model.ReconfigCostModel.from_measurements` can
 calibrate the simulator's handoff cost from *measured*, not assumed,
 reconfiguration time (``benchmarks/elastic_bench.py``).
@@ -37,10 +40,10 @@ bench can price both operational models from measurements.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import statistics
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -51,6 +54,7 @@ from repro import checkpoint as legacy_ckpt
 from repro import ckpt as ckpt_lib
 from repro import optim
 from repro import parallel as PX
+from repro import tracing
 from repro.core.leaves import TpuLeaf
 from repro.data import DataConfig, SyntheticCorpus
 from repro.elastic import plan_elastic_remesh
@@ -148,6 +152,10 @@ class HandoffMeasurement:
     first_step_s: float           # first step on the new mesh (incl. jit)
     setup_s: float = 0.0          # new-mesh state build (init + zero1 jit)
     compile_s: float = 0.0        # first_step_s minus steady step time
+    # backend compiles (persistent-cache reads included) the first step
+    # on the new mesh ran, and their seconds: the recompile, measured
+    first_step_compiles: int = 0
+    first_step_compile_s: float = 0.0
     # total bytes the measuring process wrote/read: on the single-host
     # fake-device mesh one process moves EVERY rank's shards, so
     # bytes/seconds is the storage throughput a real per-rank writer
@@ -323,18 +331,17 @@ class ElasticDriver:
         Times both phases into ``_resume_timing`` — on a resumed run this
         restore is the *receiving* half of a cross-process handoff, and
         the cluster runtime calibrates from it."""
-        t0 = time.perf_counter()
-        ctx = self._setup(shape, seed)
-        setup_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        rstep, (ctx.params, ctx.state) = ckpt_lib.restore_auto(
-            path, (ctx.params, ctx.state),
-            shardings=ctx.shardings,
-            layout=ctx.layout if self.mode == "handoff" else None,
-            retry=self.retry)
+        with tracing.span("handoff.setup") as setup:
+            ctx = self._setup(shape, seed)
+        with tracing.span("handoff.restore") as restore:
+            rstep, (ctx.params, ctx.state) = ckpt_lib.restore_auto(
+                path, (ctx.params, ctx.state),
+                shardings=ctx.shardings,
+                layout=ctx.layout if self.mode == "handoff" else None,
+                retry=self.retry)
         self._resume_timing = {
-            "setup_s": setup_s,
-            "restore_s": time.perf_counter() - t0,
+            "setup_s": setup.seconds,
+            "restore_s": restore.seconds,
             "restore_bytes": _dir_bytes(path),
         }
         if rstep != step:
@@ -360,9 +367,8 @@ class ElasticDriver:
                 f"handoff would restore that stale state — use a fresh "
                 f"directory for this elastic run")
 
-        t0 = time.perf_counter()
-        self._save(ctx, step)
-        save_s = time.perf_counter() - t0
+        with tracing.span("handoff.save") as save:
+            self._save(ctx, step)
         save_bytes = _dir_bytes(sdir)
 
         # the remesh plan validates the commit: it refuses a handoff
@@ -384,21 +390,19 @@ class ElasticDriver:
         # building the new-mesh state (param init + jitted sharded-zero1
         # init) is real handoff work — time it so the calibrated
         # recompile cost does not undercount the cycle
-        t0 = time.perf_counter()
-        new = self._setup(event.mesh_shape, seed)
-        setup_s = time.perf_counter() - t0
+        with tracing.span("handoff.setup") as setup:
+            new = self._setup(event.mesh_shape, seed)
 
-        t0 = time.perf_counter()
-        if self.mode == "handoff":
-            rstep, (new.params, new.state) = ckpt_lib.restore_sharded(
-                plan.handoff.step_dir, (new.params, new.state),
-                shardings=new.shardings, layout=new.layout,
-                retry=self.retry)
-        else:
-            rstep, (new.params, new.state) = legacy_ckpt.restore(
-                plan.handoff.step_dir, (new.params, new.state),
-                shardings=new.shardings)
-        restore_s = time.perf_counter() - t0
+        with tracing.span("handoff.restore") as restore:
+            if self.mode == "handoff":
+                rstep, (new.params, new.state) = ckpt_lib.restore_sharded(
+                    plan.handoff.step_dir, (new.params, new.state),
+                    shardings=new.shardings, layout=new.layout,
+                    retry=self.retry)
+            else:
+                rstep, (new.params, new.state) = legacy_ckpt.restore(
+                    plan.handoff.step_dir, (new.params, new.state),
+                    shardings=new.shardings)
         assert rstep == step, (rstep, step)
         maybe_fire("driver.post_restore")
 
@@ -406,8 +410,10 @@ class ElasticDriver:
         if self.verify:
             # the PR-4 bitwise handoff invariant, checked in place: the
             # resharded state is the saved state, bit for bit
-            if not _trees_equal((ctx.params, ctx.state),
-                                (new.params, new.state)):
+            with tracing.span("handoff.verify"):
+                same = _trees_equal((ctx.params, ctx.state),
+                                    (new.params, new.state))
+            if not same:
                 raise RuntimeError(
                     f"handoff not bitwise: {ctx.shape} -> "
                     f"{event.mesh_shape} at step {step}")
@@ -415,8 +421,8 @@ class ElasticDriver:
 
         return new, HandoffMeasurement(
             step=step, from_shape=ctx.shape, to_shape=new.shape,
-            mode=self.mode, save_s=save_s, restore_s=restore_s,
-            first_step_s=0.0, setup_s=setup_s, save_bytes=save_bytes,
+            mode=self.mode, save_s=save.seconds, restore_s=restore.seconds,
+            first_step_s=0.0, setup_s=setup.seconds, save_bytes=save_bytes,
             restore_bytes=save_bytes, state_bytes=state_bytes,
             verified=verified)
 
@@ -546,31 +552,47 @@ class ElasticDriver:
                     and step % save_every == 0):
                 # periodic commit of the pre-step state; a handoff at
                 # this step already saved it
-                self._save(ctx, step)
-            batch = put_batch(corpus.batch(step), ctx.rules)
+                with tracing.span("train.ckpt"):
+                    self._save(ctx, step)
+            with tracing.step_span("train.step", step):
+                with tracing.span("train.put_batch"):
+                    batch = put_batch(corpus.batch(step), ctx.rules)
+                if first_step:
+                    maybe_fire("driver.first_step")
+                # the first step on a new mesh counts its compiles (or
+                # cache reads) for its handoff's measurement
+                fills = (first_step and measurements
+                         and measurements[-1].first_step_s == 0.0)
+                with (tracing.recording() if fills
+                      else contextlib.nullcontext()) as rec, ctx.mesh:
+                    with tracing.span("train.dispatch") as dispatch:
+                        out = ctx.step_fn(ctx.params, ctx.state, batch)
+                    with tracing.span("train.device_wait") as done:
+                        ctx.params, ctx.state, metrics = \
+                            jax.block_until_ready(out)
+                with tracing.span("train.readback"):
+                    losses.append(float(metrics["loss"]))
+            shapes.append(ctx.shape)
+            dt = (done.end_ns - dispatch.start_ns) * 1e-9
             if first_step:
-                maybe_fire("driver.first_step")
-            t0 = time.perf_counter()
-            with ctx.mesh:
-                ctx.params, ctx.state, metrics = jax.block_until_ready(
-                    ctx.step_fn(ctx.params, ctx.state, batch))
-            dt = time.perf_counter() - t0
-            if first_step:
-                if measurements and measurements[-1].first_step_s == 0.0:
-                    measurements[-1].first_step_s = dt
+                if fills:
+                    m = measurements[-1]
+                    compiles = rec.totals.get(tracing.COMPILE,
+                                              tracing.Total())
+                    m.first_step_s = dt
+                    m.first_step_compiles = compiles.count
+                    m.first_step_compile_s = compiles.sum * 1e-9
                 if step == start_step:
                     run_first_step_s = dt
                 first_step = False
             else:
                 step_times.append(dt)
-            losses.append(float(metrics["loss"]))
-            shapes.append(ctx.shape)
         final_save_s = 0.0
         final_save_bytes = 0
         if final_save:
-            t0 = time.perf_counter()
-            self._save(ctx, n_steps)
-            final_save_s = time.perf_counter() - t0
+            with tracing.span("train.ckpt") as save:
+                self._save(ctx, n_steps)
+            final_save_s = save.seconds
             final_save_bytes = _dir_bytes(
                 ckpt_lib.step_dir(self.base_dir, n_steps))
         # recompile cost = first post-handoff step minus the steady step

@@ -97,21 +97,23 @@ def layer_apply(x, p, cfg: ArchConfig, *, positions, moe: bool,
     aux = jnp.zeros((), jnp.float32)
     h = L.norm_apply(x, p["attn_norm"], cfg.norm, cfg.norm_eps)
     h = shard(h, "batch", None, None)
-    if cross:
-        a = A.gqa_apply(h, p["attn"], cfg, positions=positions,
-                        kv_override=media_kv)
-        a = jnp.tanh(p["xgate"]).astype(a.dtype) * a
-    elif _use_mla(cfg):
-        a = A.mla_apply(h, p["attn"], cfg, positions=positions,
-                        causal=causal)
-    else:
-        a = A.gqa_apply(h, p["attn"], cfg, positions=positions,
-                        causal=causal)
+    with jax.named_scope("attention"):
+        if cross:
+            a = A.gqa_apply(h, p["attn"], cfg, positions=positions,
+                            kv_override=media_kv)
+            a = jnp.tanh(p["xgate"]).astype(a.dtype) * a
+        elif _use_mla(cfg):
+            a = A.mla_apply(h, p["attn"], cfg, positions=positions,
+                            causal=causal)
+        else:
+            a = A.gqa_apply(h, p["attn"], cfg, positions=positions,
+                            causal=causal)
     if cfg.parallel_block:
         if moe:
             f, aux = F.moe_apply(h, p["ffn"], cfg)
         else:
-            f = L.mlp_apply(h, p["ffn"], cfg.act)
+            with jax.named_scope("mlp"):
+                f = L.mlp_apply(h, p["ffn"], cfg.act)
         x = x + a + f
     else:
         x = x + a
@@ -120,7 +122,8 @@ def layer_apply(x, p, cfg: ArchConfig, *, positions, moe: bool,
         if moe:
             f, aux = F.moe_apply(h2, p["ffn"], cfg)
         else:
-            f = L.mlp_apply(h2, p["ffn"], cfg.act)
+            with jax.named_scope("mlp"):
+                f = L.mlp_apply(h2, p["ffn"], cfg.act)
         x = x + f
     return shard(x, "batch", "seq", None), aux
 
@@ -324,7 +327,8 @@ class TransformerLM:
         # gradient) accumulates in f32 — bf16 scatter accumulation is
         # reduction-order sensitive and breaks accum-invariance
         emb = params["embed"]
-        x = emb.astype(jnp.float32)[tokens].astype(emb.dtype)  # (B, S, D)
+        with jax.named_scope("head"):
+            x = emb.astype(jnp.float32)[tokens].astype(emb.dtype)  # (B,S,D)
         if not cfg.use_rope and not cfg.is_encdec:
             x = x + L.sinusoidal_positions(
                 tokens.shape[1], cfg.d_model).astype(x.dtype)[None]
@@ -351,8 +355,10 @@ class TransformerLM:
                                       moe=cfg.moe is not None)
             aux = aux + a1
 
-        x = L.norm_apply(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-        logits = self._logits(params, x)
+        with jax.named_scope("head"):
+            x = L.norm_apply(x, params["final_norm"], cfg.norm,
+                             cfg.norm_eps)
+            logits = self._logits(params, x)
         return logits, aux
 
     def _logits(self, params, x):
@@ -369,7 +375,8 @@ class TransformerLM:
 
     def loss(self, params, batch):
         logits, aux = self.forward_logits(params, batch)
-        nll, zl = L.softmax_xent(logits, batch["targets"])
+        with jax.named_scope("head"):
+            nll, zl = L.softmax_xent(logits, batch["targets"])
         total = nll + zl + aux
         return total, {"nll": nll, "z_loss": zl, "aux": aux}
 

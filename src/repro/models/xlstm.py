@@ -68,9 +68,13 @@ def mlstm_chunked(q, k, v, i_raw, f_raw, *, chunk: int, carry=None):
         m_t = b + rm                               # absolute stabilizer
 
         qk = jnp.einsum("bqhd,bshd->bhqs", qb, kb)
-        w = jnp.exp(a[:, None, :, :].transpose(0, 3, 1, 2) -
-                    rm.transpose(0, 2, 1)[:, :, :, None])     # (B,H,t,s)
-        w = jnp.where(tri[None, None], w, 0.0)
+        # masked before the exp: above the diagonal the exponent grows
+        # with the forget gates' decay and overflows, and a mask after
+        # the exp would send inf * 0 = NaN into the gradient
+        w = jnp.exp(jnp.where(tri[None, None],
+                              a[:, None, :, :].transpose(0, 3, 1, 2)
+                              - rm.transpose(0, 2, 1)[:, :, :, None],
+                              -jnp.inf))                  # (B,H,t,s)
         scores = qk * w
 
         inter_scale = jnp.exp(m0[:, :, None] - rm.transpose(0, 2, 1))
@@ -223,6 +227,7 @@ def _mlstm_qkvg(x, p, cfg: ArchConfig, conv_state=None):
     return xu, z, q, k, v, i_raw, f_raw
 
 
+@jax.named_scope("mlstm")
 def mlstm_block_apply(x, p, cfg: ArchConfig, *, chunk: int = 256,
                       use_kernel: bool = False):
     B, S, D = x.shape
@@ -278,6 +283,7 @@ def _slstm_gates(x, p, cfg):
     return g.reshape(B, S, 4, H, Dh).transpose(0, 1, 3, 2, 4)  # (B,S,H,4,Dh)
 
 
+@jax.named_scope("slstm")
 def slstm_block_apply(x, p, cfg: ArchConfig, carry=None):
     B, S, D = x.shape
     H = cfg.n_heads
@@ -344,13 +350,18 @@ class XLSTMLM:
 
     def forward_logits(self, params, batch):
         cfg = self.cfg
-        x = params["embed"][batch["tokens"]]
+        with jax.named_scope("head"):
+            x = params["embed"][batch["tokens"]]
         x = shard(x, "batch", None, None)
 
+        def inner(x, bp):
+            return mlstm_block_apply(x, bp, cfg), None
+
+        # the scans over mLSTM blocks are in the block's scope too: their
+        # slicing and stacking of per-block state is mLSTM work
         def super_body(x, sp):
-            def inner(x, bp):
-                return mlstm_block_apply(x, bp, cfg), None
-            x, _ = jax.lax.scan(inner, x, sp["mlstm"])
+            with jax.named_scope("mlstm"):
+                x, _ = jax.lax.scan(inner, x, sp["mlstm"])
             x, _ = slstm_block_apply(x, sp["slstm"], cfg)
             return x, None
 
@@ -358,18 +369,20 @@ class XLSTMLM:
             f = jax.checkpoint(super_body) if self.remat else super_body
             x, _ = jax.lax.scan(f, x, params["blocks"])
         if self.n_tail:
-            def inner(x, bp):
-                return mlstm_block_apply(x, bp, cfg), None
             g = jax.checkpoint(inner) if self.remat else inner
-            x, _ = jax.lax.scan(g, x, params["tail"])
-        x = L.norm_apply(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+            with jax.named_scope("mlstm"):
+                x, _ = jax.lax.scan(g, x, params["tail"])
+        with jax.named_scope("head"):
+            x = L.norm_apply(x, params["final_norm"], cfg.norm,
+                             cfg.norm_eps)
+            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
         return shard(logits, "batch", None, "vocab"), jnp.zeros(
             (), jnp.float32)
 
     def loss(self, params, batch):
         logits, aux = self.forward_logits(params, batch)
-        nll, zl = L.softmax_xent(logits, batch["targets"])
+        with jax.named_scope("head"):
+            nll, zl = L.softmax_xent(logits, batch["targets"])
         return nll + zl, {"nll": nll, "z_loss": zl, "aux": aux}
 
     # ------------------------------------------------------------- decode

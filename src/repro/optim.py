@@ -126,6 +126,7 @@ def _adamw_update(cfg: AdamWConfig, g, m, v, base, *, lr, b1c, b2c,
     return m, v, new_w
 
 
+@jax.named_scope("optimizer")
 def apply(cfg: AdamWConfig, params, grads, state: OptState, *,
           gnorm=None):
     """One AdamW step.  Returns (new_params, new_state, metrics).
@@ -172,6 +173,7 @@ def apply(cfg: AdamWConfig, params, grads, state: OptState, *,
     return new_params, OptState(step, new_mu, new_nu, new_master), metrics
 
 
+@jax.named_scope("optimizer")
 def apply_flat(cfg: AdamWConfig, grads, state: BucketedOptState, *,
                gnorm) -> Tuple[BucketedOptState, Dict[str, jax.Array]]:
     """Shard-resident AdamW over flat f32 bucket (shards).

@@ -27,15 +27,14 @@ opt, metrics) function with:
                       all-gathered instead of gradients.  Bitwise-
                       identical losses to ``hier_bucketed``.
 
-``Trainer`` adds checkpoint/restart, heartbeats, straggler detection and
-failure injection around the step function.
+``Trainer`` adds checkpoint/restart, straggler detection, failure
+injection and host spans (``repro.tracing``) around the step function.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import os
-import time
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -47,11 +46,12 @@ from repro import checkpoint as legacy_ckpt
 from repro import ckpt as ckpt_lib
 from repro import optim
 from repro import parallel as PX
+from repro import tracing
 from repro.collectives import bucketing
 from repro.collectives import deterministic as det
 from repro.collectives.hierarchical import hier_all_reduce_mean
 from repro.data import DataConfig, Prefetcher, SyntheticCorpus
-from repro.elastic import HeartbeatMonitor, StragglerDetector
+from repro.elastic import StragglerDetector
 from repro.sharding import (MeshRules, batch_axes, fit_spec,
                             grad_sync_axes, use_rules, without_axes)
 
@@ -714,7 +714,6 @@ class TrainerConfig:
     log_every: int = 10
     accum: int = 1
     async_ckpt: bool = True
-    heartbeat_timeout_s: float = 60.0
     cross_pod_mode: str = "xla"
     bucket_bytes: int = bucketing.DEFAULT_BUCKET_BYTES
     slow_compress_bits: int = 0
@@ -744,8 +743,6 @@ class Trainer:
         self.data_cfg = data_cfg
         self.rules = rules
         self.failure_hook = failure_hook
-        self.heartbeat = HeartbeatMonitor(
-            timeout_s=tcfg.heartbeat_timeout_s)
         self.straggler = StragglerDetector()
         self.step_fn = make_jitted_train_step(
             model, ocfg, accum=tcfg.accum, rules=rules,
@@ -809,6 +806,19 @@ class Trainer:
             pol = opt_policy(opt_state)
         return (exact(params), pol)
 
+    def _save(self, pending, step: int, params, opt_state, mesh):
+        """Join the previous save, start checkpoint ``step``; returns
+        the new save's handle."""
+        if pending is not None:
+            pending.join()
+        sdir = ckpt_lib.step_dir(self.tcfg.ckpt_dir, step)
+        if self.tcfg.save_sharded:
+            return ckpt_lib.save_sharded(
+                sdir, step, (params, opt_state), layout=self._layout,
+                mesh=mesh, blocking=not self.tcfg.async_ckpt)
+        return legacy_ckpt.save(sdir, step, (params, opt_state),
+                                blocking=not self.tcfg.async_ckpt)
+
     def _run(self, *, seed: int, resume: bool) -> Dict[str, Any]:
         from repro.faults.recovery import restore_with_fallback
         from repro.faults.retry import RetryPolicy
@@ -816,11 +826,12 @@ class Trainer:
         start = 0
         recovery = None
         retry = RetryPolicy(max_retries=tcfg.max_restore_retries)
-        params, opt_state = self._init_state(seed)
+        with tracing.span("train.init_state"):
+            params, opt_state = self._init_state(seed)
         mesh = self.rules.mesh if self.rules is not None else None
-        if resume:
-            last = ckpt_lib.latest_step(tcfg.ckpt_dir)
-            if last is not None:
+        last = ckpt_lib.latest_step(tcfg.ckpt_dir) if resume else None
+        if last is not None:
+            with tracing.span("train.restore"):
                 # restore the zero1 state straight onto its fast-axis
                 # shards — an unsharded restore would replicate the full
                 # f32 masters on every device until the first step
@@ -845,32 +856,30 @@ class Trainer:
             for step in range(start, tcfg.n_steps):
                 if self.failure_hook and self.failure_hook(step):
                     raise RuntimeError(f"injected failure at step {step}")
-                t0 = time.perf_counter()
-                _, batch = prefetch.next()
-                params, opt_state, metrics = jax.block_until_ready(
-                    self.step_fn(params, opt_state,
-                                 put_batch(batch, self.rules)))
-                dt = time.perf_counter() - t0
-                self.heartbeat.beat(worker=0, t=time.time())
-                self.straggler.record(dt)
-                if step % tcfg.log_every == 0:
-                    self.history.append(
-                        {"step": step,
-                         "loss": float(metrics["loss"]),
-                         "sec_per_step": dt})
-                if (step + 1) % tcfg.ckpt_every == 0:
-                    if pending is not None:
-                        pending.join()
-                    sdir = ckpt_lib.step_dir(tcfg.ckpt_dir, step + 1)
-                    if tcfg.save_sharded:
-                        pending = ckpt_lib.save_sharded(
-                            sdir, step + 1, (params, opt_state),
-                            layout=self._layout, mesh=mesh,
-                            blocking=not tcfg.async_ckpt)
-                    else:
-                        pending = legacy_ckpt.save(
-                            sdir, step + 1, (params, opt_state),
-                            blocking=not tcfg.async_ckpt)
+                with tracing.step_span("train.step", step):
+                    with tracing.span("train.data_wait") as wait:
+                        _, batch = prefetch.next()
+                    with tracing.span("train.put_batch"):
+                        batch = put_batch(batch, self.rules)
+                    with tracing.span("train.dispatch"):
+                        out = self.step_fn(params, opt_state, batch)
+                    with tracing.span("train.device_wait") as done:
+                        params, opt_state, metrics = \
+                            jax.block_until_ready(out)
+                    # data wait through device wait: the step as the
+                    # host sees it, without readback or checkpoint
+                    dt = (done.end_ns - wait.start_ns) * 1e-9
+                    with tracing.span("train.readback"):
+                        self.straggler.record(dt)
+                        if step % tcfg.log_every == 0:
+                            self.history.append(
+                                {"step": step,
+                                 "loss": float(metrics["loss"]),
+                                 "sec_per_step": dt})
+                    if (step + 1) % tcfg.ckpt_every == 0:
+                        with tracing.span("train.ckpt"):
+                            pending = self._save(pending, step + 1,
+                                                 params, opt_state, mesh)
         finally:
             if pending is not None:
                 pending.join()

@@ -129,7 +129,7 @@ def test_elastic_driver_smoke_multidevice():
     elastic-e2e step runs exactly this in both device-matrix legs)."""
     out = run_multidevice("""
         import tempfile
-        from repro import optim
+        from repro import optim, tracing
         from repro.data import DataConfig
         from repro.elastic_driver import ElasticDriver, ReconfigEvent
         from repro.models.registry import get_config, build_model, \\
@@ -144,10 +144,11 @@ def test_elastic_driver_smoke_multidevice():
         ref = ElasticDriver(model, ocfg, dcfg,
                             base_dir=tempfile.mkdtemp()).run(
             4, (), initial_shape=(2, 2))
-        out = ElasticDriver(model, ocfg, dcfg,
-                            base_dir=tempfile.mkdtemp()).run(
-            4, [ReconfigEvent(step=2, mesh_shape=(4, 1))],
-            initial_shape=(2, 2))
+        with tracing.recording() as rec:
+            out = ElasticDriver(model, ocfg, dcfg,
+                                base_dir=tempfile.mkdtemp()).run(
+                4, [ReconfigEvent(step=2, mesh_shape=(4, 1))],
+                initial_shape=(2, 2))
         assert out.losses == ref.losses, (out.losses, ref.losses)
         assert out.mesh_shapes[:2] == [(2, 2)] * 2
         assert out.mesh_shapes[2:] == [(4, 1)] * 2
@@ -155,6 +156,18 @@ def test_elastic_driver_smoke_multidevice():
         assert m.verified
         assert m.save_s > 0 and m.restore_s > 0
         assert m.save_bytes > 0 and m.state_bytes > 0
+        # each phase is a span, and each duration is read from its span
+        (save,) = rec.named('handoff.save')
+        (setup,) = rec.named('handoff.setup')
+        (restore,) = rec.named('handoff.restore')
+        assert len(rec.named('handoff.verify')) == 1
+        assert (m.save_s, m.setup_s, m.restore_s) == (
+            save.seconds, setup.seconds, restore.seconds)
+        steps = rec.named('train.step')
+        assert len(steps) == 4
+        assert save.end_ns <= restore.start_ns <= steps[2].start_ns
+        # the first step on (4,1) compiles its own program
+        assert m.first_step_compiles >= 1 and m.first_step_compile_s > 0
         print('ELASTIC_SMOKE_OK')
         """, n_devices=8)
     assert "ELASTIC_SMOKE_OK" in out

@@ -91,6 +91,25 @@ def test_mlstm_chunk_invariance_property(T, chunk, seed):
                                rtol=5e-3, atol=5e-3)
 
 
+@pytest.mark.parametrize("f_raw", [3.0, -10.0, -40.0])
+def test_mlstm_gradient_finite_under_closing_forget_gates(f_raw):
+    """Forget gates far below zero make the gate exponent above the
+    causal diagonal overflow float32; the output and the gradient must
+    stay finite all the same."""
+    B, T, H, D = 1, 32, 1, 4
+    ks = jax.random.split(jax.random.key(3), 3)
+    q, k, v = (jax.random.normal(x, (B, T, H, D)) for x in ks)
+    ir = jnp.zeros((B, T, H))
+
+    def loss(fr):
+        return X.mlstm_chunked(q, k, v, ir, fr, chunk=16)[0].sum()
+
+    fr = jnp.full((B, T, H), f_raw)
+    h, _ = X.mlstm_chunked(q, k, v, ir, fr, chunk=16)
+    g = jax.grad(loss)(fr)
+    assert bool(jnp.all(jnp.isfinite(h))) and bool(jnp.all(jnp.isfinite(g)))
+
+
 def test_decode_state_matches_chunked_ssm():
     """Mamba decode recurrence continues exactly where prefill stopped."""
     B, T, H, P, G, N = 1, 32, 2, 8, 1, 4
