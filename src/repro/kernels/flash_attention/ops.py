@@ -1,4 +1,7 @@
-"""jit'd public wrapper for the flash-attention kernel."""
+"""jit'd public wrapper for the flash-attention kernels, differentiable
+through a ``jax.custom_vjp`` whose backward is the Pallas dK/dV and dQ
+kernels.  The residuals are q, k, v, o and the per-query log-sum-exp:
+no (block_q, block_k) tile is saved."""
 from __future__ import annotations
 
 import functools
@@ -7,7 +10,14 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention.kernel import flash_attention_bhsd
+from repro.kernels.flash_attention.kernel import flash_bwd, flash_fwd
+
+# (block_q, block_k) of the forward and of both backward kernels: the
+# fastest of {256, 512, 1024}^2 for each, forward and backward alike, on a
+# TPU v5e at B 4, S 4096, H = Kv 32, D 64, bf16
+# (``scripts/flash_block_sweep.py``; PERF.md).  Larger blocks exceed the
+# 16 MiB of VMEM a kernel may use by default.
+BLOCKS = (1024, 1024)
 
 
 def _pick_block(s: int, target: int) -> int:
@@ -19,22 +29,66 @@ def _pick_block(s: int, target: int) -> int:
     return max(b, 1)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "softcap", "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, softcap: float = 0.0,
-                    interpret: bool = False):
-    """q: (B, S, H, D); k/v: (B, S, Kv, Dv).  Returns (B, S, H, Dv)."""
+def _heads_major(x):
+    """(B, S, N, D) -> (B*N, S, D)."""
+    B, S, N, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B * N, S, D)
+
+
+def _forward(q, k, v, causal, softcap, blocks, interpret):
+    """o (B, S, H, Dv) and the log-sum-exp of each query's scaled scores,
+    (B*H, 1, S) f32."""
+    B, S, H, _ = q.shape
+    Kv, Dv = k.shape[2], v.shape[-1]
+    vt = v.transpose(0, 2, 3, 1).reshape(B * Kv, Dv, S)
+    ot, lse = flash_fwd(_heads_major(q), _heads_major(k), vt,
+                        causal=causal, group=H // Kv, block_q=blocks[0],
+                        block_k=blocks[1], softcap=softcap,
+                        interpret=interpret)
+    return ot.reshape(B, H, Dv, S).transpose(0, 3, 1, 2), lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, softcap, blocks, interpret):
+    return _forward(q, k, v, causal, softcap, blocks, interpret)[0]
+
+
+def _flash_fwd(q, k, v, causal, softcap, blocks, interpret):
+    o, lse = _forward(q, k, v, causal, softcap, blocks, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bwd(causal, softcap, blocks, interpret, res, do):
+    q, k, v, o, lse = res
     B, S, H, D = q.shape
     Kv = k.shape[2]
-    Dv = v.shape[-1]
-    G = H // Kv
-    bq = _pick_block(S, block_q)
-    bk = _pick_block(S, block_k)
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * Kv, S, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * Kv, S, Dv)
-    o = flash_attention_bhsd(qf, kf, vf, causal=causal, group=G,
-                             block_q=bq, block_k=bk, softcap=softcap,
-                             interpret=interpret)
-    return o.reshape(B, H, S, Dv).transpose(0, 2, 1, 3)
+    di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    di = di.transpose(0, 2, 1).reshape(B * H, 1, S)
+    dqt, dk, dv = flash_bwd(
+        _heads_major(q), _heads_major(k), _heads_major(v), _heads_major(do),
+        lse, di, causal=causal, group=H // Kv, block_q=blocks[0],
+        block_k=blocks[1], softcap=softcap, interpret=interpret)
+    dq = dqt.reshape(B, H, D, S).transpose(0, 3, 1, 2)
+    dk = dk.reshape(B, Kv, S, -1).transpose(0, 2, 1, 3)
+    dv = dv.reshape(B, Kv, S, -1).transpose(0, 2, 1, 3)
+    return dq, dk, dv
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+                                             "softcap", "interpret"))
+def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
+                    block_k=None, softcap: float = 0.0,
+                    interpret: bool = False):
+    """q: (B, S, H, D); k/v: (B, S, Kv, Dv).  Returns (B, S, H, Dv).
+
+    ``block_q`` / ``block_k`` override the tiling of every kernel; by
+    default ``BLOCKS``, cut to divisors of S.
+    """
+    S = q.shape[1]
+    blocks = (_pick_block(S, block_q or BLOCKS[0]),
+              _pick_block(S, block_k or BLOCKS[1]))
+    return _flash(q, k, v, causal, softcap, blocks, interpret)
+

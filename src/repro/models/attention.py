@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
+from repro.kernels.flash_attention import flash_attention
 from repro.models import layers as L
 from repro import parallel as PX
 from repro.sharding import current_rules, shard
@@ -183,6 +184,24 @@ def _rope_dims(cfg: ArchConfig) -> int:
     return rd - (rd % 2)
 
 
+def use_flash_kernel(q, k, softcap: float) -> bool:
+    """Whether ``gqa_apply`` runs the Pallas flash-attention kernel
+    (forward and backward) in place of ``L.blocked_attention``.
+
+    Only where the kernel covers the case on one device: the backend is
+    TPU, self-attention (Sq == Sk, so no ``kv_override``), S a multiple
+    of 128 (the kernel's lane tiling; whisper's 1500 is not), no logit
+    softcap, and no mesh of more than one device (the operands are not
+    split).  q: (B, Sq, H, D); k: (B, Sk, Kv, D).
+    """
+    S = q.shape[1]
+    if (jax.default_backend() != "tpu" or S != k.shape[1] or S % 128
+            or softcap):
+        return False
+    rules = current_rules()
+    return rules is None or rules.mesh is None or rules.mesh.size == 1
+
+
 def gqa_apply(x, p, cfg: ArchConfig, *, positions: jax.Array,
               causal: bool = True,
               kv_override: Optional[Tuple[jax.Array, jax.Array]] = None,
@@ -206,6 +225,9 @@ def gqa_apply(x, p, cfg: ArchConfig, *, positions: jax.Array,
     if q.shape[1] * k.shape[1] <= 1024 * 1024:
         o = L.full_attention(q, k, v, causal=causal,
                              softcap=cfg.logit_softcap)
+    elif use_flash_kernel(q, k, cfg.logit_softcap):
+        with jax.named_scope("flash_attention"):
+            o = flash_attention(q, k, v, causal=causal)
     else:
         o = L.blocked_attention(q, k, v, causal=causal, block_q=block_q,
                                 block_k=block_k, softcap=cfg.logit_softcap)

@@ -9,6 +9,7 @@ try:
 except ImportError:      # no-network env: deterministic example-based shim
     from tests._hypothesis_stub import given, settings, st
 
+from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.mamba_scan.ops import ssd
@@ -70,6 +71,80 @@ def test_flash_attention_block_invariance(bq, bk):
     b = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=3e-5, atol=3e-5)
+
+
+def _attention_grads(fn, q, k, v, ct):
+    """d<fn(q, k, v), ct>/d(q, k, v)."""
+    return jax.grad(lambda q, k, v: jnp.sum(
+        fn(q, k, v).astype(jnp.float32) * ct), (0, 1, 2))(q, k, v)
+
+
+def _assert_grads_close(got, want, tol):
+    for name, g, w in zip("qkv", got, want):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        err = np.max(np.abs(g - w)) / np.max(np.abs(w))
+        assert err < tol, f"d{name}: max error {err:.3g} of the largest"
+
+
+@pytest.mark.parametrize("S,H,Kv,D", [
+    (128, 4, 4, 64),      # MHA
+    (256, 4, 2, 64),      # GQA 2:1
+    (128, 8, 2, 128),     # GQA 4:1, MXU-width head
+    (192, 2, 1, 32),      # non-pow2 seq, MQA
+])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grad_sweep(S, H, Kv, D, causal, dtype):
+    """dq, dk, dv of the kernel's custom VJP against autodiff through the
+    oracle, in float32 on the same (rounded) inputs.  block_q != block_k,
+    so the backward's causal block skip meets blocks cut by the diagonal
+    and blocks wholly above it."""
+    ks = jax.random.split(jax.random.key(7), 4)
+    q = jax.random.normal(ks[0], (1, S, H, D), dtype)
+    k = jax.random.normal(ks[1], (1, S, Kv, D), dtype)
+    v = jax.random.normal(ks[2], (1, S, Kv, D), dtype)
+    ct = jax.random.normal(ks[3], (1, S, H, D))
+    got = _attention_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal, block_q=64,
+                                        block_k=32, interpret=True),
+        q, k, v, ct)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want = _attention_grads(
+        lambda q, k, v: attention_ref(q, k, v, causal=causal), *f32, ct)
+    _assert_grads_close(got, want, 2e-5 if dtype == jnp.float32 else 3e-2)
+
+
+def test_flash_attention_softcap_grad():
+    """The backward carries the cap's tanh derivative."""
+    ks = jax.random.split(jax.random.key(8), 4)
+    q, k, v, ct = (jax.random.normal(kk, (1, 128, 2, 32)) for kk in ks)
+    got = _attention_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, softcap=5.0,
+                                        block_q=64, block_k=32,
+                                        interpret=True), q, k, v, ct)
+    want = _attention_grads(
+        lambda q, k, v: attention_ref(q, k, v, causal=True, softcap=5.0),
+        q, k, v, ct)
+    _assert_grads_close(got, want, 2e-5)
+
+
+def test_flash_attention_lse_residual():
+    """The forward's log-sum-exp residual is logsumexp of the scaled,
+    causally masked reference scores."""
+    ks = jax.random.split(jax.random.key(9), 3)
+    B, S, H, Kv, D = 1, 256, 4, 2, 64
+    q = jax.random.normal(ks[0], (B, S, H, D))
+    k = jax.random.normal(ks[1], (B, S, Kv, D))
+    v = jax.random.normal(ks[2], (B, S, Kv, D))
+    _, lse = jax.jit(flash_ops._forward, static_argnums=(3, 4, 5, 6))(
+        q, k, v, True, 0.0, (64, 128), True)
+    kg = jnp.repeat(k, H // Kv, axis=2)
+    s = jnp.einsum("bqhd,bshd->bhqs", q, kg,
+                   precision=jax.lax.Precision.HIGHEST) / np.sqrt(D)
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
+    want = jax.scipy.special.logsumexp(s, axis=-1)
+    np.testing.assert_allclose(np.asarray(lse).reshape(B, H, S),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("T,H,P,G,N,chunk", [

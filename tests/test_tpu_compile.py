@@ -67,6 +67,36 @@ def test_flash_attention_compiles_for_v5e(one_chip, H, Kv, D):
     assert "tpu_custom_call" in txt
 
 
+def test_gqa_apply_grad_compiles_to_flash_kernels_for_v5e(one_chip,
+                                                         monkeypatch):
+    """stablelm-1.6b attention at its published width and context (B 4,
+    S 4096, H = Kv 32, D 64, bf16): on a TPU ``gqa_apply`` and its
+    gradient run the flash kernels (forward, dK/dV, dQ) in place of the
+    blocked XLA loop."""
+    from repro.models import attention as A
+    from repro.models.registry import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("stablelm-1.6b")
+    B, S = 4, 4096
+    params = jax.eval_shape(lambda key: A.gqa_init(key, cfg),
+                            jax.random.key(0))
+    shapes = [((B, S, cfg.d_model), BF16)] + [
+        (w.shape, w.dtype) for w in jax.tree.leaves(params)]
+    treedef = jax.tree.structure(params)
+
+    def grads(x, *leaves):
+        def loss(x, p):
+            out = A.gqa_apply(x, p, cfg, positions=jnp.arange(S))
+            return jnp.sum(out.astype(F32))
+        return jax.grad(loss, (0, 1))(
+            x, jax.tree.unflatten(treedef, leaves))
+
+    txt = _compile_text(grads, shapes, one_chip)
+    assert txt.count('custom_call_target="tpu_custom_call"') >= 3
+    assert "while" not in txt
+
+
 def test_mlstm_compiles_for_v5e(one_chip):
     # xlstm-125m: d_model 768 -> 2x up-projection 1536 over 4 heads
     B, S, H, D = 1, 2048, 4, 384
