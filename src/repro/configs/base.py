@@ -17,15 +17,26 @@ class MoEConfig:
     top_k: int = 2
     d_ff: int = 0                # per-expert hidden dim
     n_padded: int = 0            # routed experts padded for EP divisibility
-    capacity_factor: float = 1.25
+    # the experts whose weights this model holds: ``n_held`` of them from
+    # index ``first_held`` (0: all).  The router still scores every
+    # expert; pairs routed to an expert held elsewhere add nothing here
+    # (one chip's share of an expert-parallel layer), and the router
+    # learns from the balance loss alone.
+    n_held: int = 0
+    first_held: int = 0
     router_z_coef: float = 1e-3
-    aux_coef: float = 1e-2
+    aux_coef: float = 1e-2       # expert balance loss
+    seq_aux: bool = False        # balance loss per sequence (DeepSeek-V2)
     first_dense_layers: int = 0  # leading layers that use a dense FFN instead
     dense_d_ff: int = 0          # hidden dim of those dense layers
 
     @property
     def n_experts_padded(self) -> int:
         return self.n_padded or self.n_routed
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.n_held or self.n_experts_padded
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +45,17 @@ class MLAConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling, in the keys of a HF ``rope_scaling`` entry."""
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,6 +87,7 @@ class ArchConfig:
     head_dim: int = 0            # 0 -> d_model // n_heads
     rope_theta: float = 10000.0
     rope_fraction: float = 1.0   # partial RoPE (stablelm = 0.25)
+    rope_scaling: Optional[YaRNConfig] = None
     use_rope: bool = True
     norm: str = "rmsnorm"        # rmsnorm | layernorm
     norm_eps: float = 1e-5
@@ -101,6 +124,15 @@ class ArchConfig:
 
     max_seq: int = 532_480
     source: str = ""
+
+    def __post_init__(self):
+        # nested groups given as dicts (a config read from JSON) become
+        # their dataclasses, so the config stays hashable
+        for name, cls in (("moe", MoEConfig), ("mla", MLAConfig),
+                          ("ssm", SSMConfig), ("rope_scaling", YaRNConfig)):
+            value = getattr(self, name)
+            if isinstance(value, dict):
+                object.__setattr__(self, name, cls(**value))
 
     # ---------------------------------------------------------------- utils
     @property
@@ -145,6 +177,5 @@ def shape_applicable(cfg: ArchConfig, shape: ShapeConfig) -> Tuple[bool, str]:
     """Whether a (arch x shape) cell runs; returns (ok, reason_if_skipped)."""
     if shape.name == "long_500k" and not cfg.sub_quadratic:
         return False, ("pure full-attention arch: 524k-token dense-attention "
-                       "decode is the quadratic regime long_500k excludes "
-                       "(see DESIGN.md §4)")
+                       "decode is the quadratic regime long_500k excludes")
     return True, ""

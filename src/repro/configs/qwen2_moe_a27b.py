@@ -2,8 +2,7 @@
 
 [hf:Qwen/Qwen1.5-MoE-A2.7B; hf]  24L, d_model=2048, 16H (kv=16), expert
 d_ff=1408, vocab=151936.  Routed experts padded 60 -> 64 so the expert axis
-divides the 16-way model mesh axis (pad experts receive ~0 router mass at
-init; see DESIGN.md §8).
+divides the 16-way model mesh axis (the router masks the pad experts out).
 """
 from repro.configs.base import ArchConfig, MoEConfig
 
@@ -23,7 +22,6 @@ CONFIG = ArchConfig(
         top_k=4,
         d_ff=1408,
         n_padded=64,
-        capacity_factor=1.25,
     ),
     sub_quadratic=False,
     source="hf:Qwen/Qwen1.5-MoE-A2.7B; hf",

@@ -124,8 +124,10 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_ref, l_ref,
 
 
 def flash_fwd(q, k, vt, *, causal: bool, group: int, block_q: int,
-              block_k: int, softcap: float = 0.0, interpret: bool = False):
-    """q: (BH, S, D); k: (BKv, S, D); vt: (BKv, Dv, S); group = H // Kv.
+              block_k: int, softcap: float = 0.0, scale=None,
+              interpret: bool = False):
+    """q: (BH, S, D); k: (BKv, S, D); vt: (BKv, Dv, S); group = H // Kv;
+    ``scale`` multiplies q k^T (default ``1/sqrt(D)``).
 
     Returns o^T (BH, Dv, S) in q's dtype and the log-sum-exp of each
     query's scaled scores, (BH, 1, S) f32.
@@ -133,8 +135,9 @@ def flash_fwd(q, k, vt, *, causal: bool, group: int, block_q: int,
     BH, S, D = q.shape
     Dv = vt.shape[1]
     blocks = dict(causal=causal, block_q=block_q, block_k=block_k)
-    kernel = functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(D),
-                               softcap=softcap, **blocks)
+    kernel = functools.partial(
+        _fwd_kernel, scale=1.0 / math.sqrt(D) if scale is None else scale,
+        softcap=softcap, **blocks)
 
     def kv_map(b, qi, ki):
         return b // group, _last_live_k(qi, ki, **blocks), 0
@@ -229,10 +232,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, di_ref,
 
 
 def flash_bwd(q, k, v, do, lse, di, *, causal: bool, group: int,
-              block_q: int, block_k: int, softcap: float = 0.0,
+              block_q: int, block_k: int, softcap: float = 0.0, scale=None,
               interpret: bool = False):
     """q: (BH, S, D); k: (BKv, S, D); v: (BKv, S, Dv); do: (BH, S, Dv);
-    lse, di = rowsum(dO o): (BH, 1, S) f32.
+    lse, di = rowsum(dO o): (BH, 1, S) f32; ``scale`` as the forward's.
 
     Returns dq^T (BH, D, S), dk (BKv, S, D), dv (BKv, S, Dv).
     """
@@ -240,7 +243,7 @@ def flash_bwd(q, k, v, do, lse, di, *, causal: bool, group: int,
     BKv, _, Dv = v.shape
     nq, nk = S // block_q, S // block_k
     blocks = dict(causal=causal, block_q=block_q, block_k=block_k)
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
 
     # dK, dV: kv block outer; (query head of the group, q block) inner
     def q_map(b, ki, t):

@@ -35,7 +35,7 @@ def _heads_major(x):
     return x.transpose(0, 2, 1, 3).reshape(B * N, S, D)
 
 
-def _forward(q, k, v, causal, softcap, blocks, interpret):
+def _forward(q, k, v, causal, softcap, scale, blocks, interpret):
     """o (B, S, H, Dv) and the log-sum-exp of each query's scaled scores,
     (B*H, 1, S) f32."""
     B, S, H, _ = q.shape
@@ -43,22 +43,22 @@ def _forward(q, k, v, causal, softcap, blocks, interpret):
     vt = v.transpose(0, 2, 3, 1).reshape(B * Kv, Dv, S)
     ot, lse = flash_fwd(_heads_major(q), _heads_major(k), vt,
                         causal=causal, group=H // Kv, block_q=blocks[0],
-                        block_k=blocks[1], softcap=softcap,
+                        block_k=blocks[1], softcap=softcap, scale=scale,
                         interpret=interpret)
     return ot.reshape(B, H, Dv, S).transpose(0, 3, 1, 2), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, softcap, blocks, interpret):
-    return _forward(q, k, v, causal, softcap, blocks, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, softcap, scale, blocks, interpret):
+    return _forward(q, k, v, causal, softcap, scale, blocks, interpret)[0]
 
 
-def _flash_fwd(q, k, v, causal, softcap, blocks, interpret):
-    o, lse = _forward(q, k, v, causal, softcap, blocks, interpret)
+def _flash_fwd(q, k, v, causal, softcap, scale, blocks, interpret):
+    o, lse = _forward(q, k, v, causal, softcap, scale, blocks, interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, softcap, blocks, interpret, res, do):
+def _flash_bwd(causal, softcap, scale, blocks, interpret, res, do):
     q, k, v, o, lse = res
     B, S, H, D = q.shape
     Kv = k.shape[2]
@@ -67,7 +67,8 @@ def _flash_bwd(causal, softcap, blocks, interpret, res, do):
     dqt, dk, dv = flash_bwd(
         _heads_major(q), _heads_major(k), _heads_major(v), _heads_major(do),
         lse, di, causal=causal, group=H // Kv, block_q=blocks[0],
-        block_k=blocks[1], softcap=softcap, interpret=interpret)
+        block_k=blocks[1], softcap=softcap, scale=scale,
+        interpret=interpret)
     dq = dqt.reshape(B, H, D, S).transpose(0, 3, 1, 2)
     dk = dk.reshape(B, Kv, S, -1).transpose(0, 2, 1, 3)
     dv = dv.reshape(B, Kv, S, -1).transpose(0, 2, 1, 3)
@@ -78,17 +79,19 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "softcap", "interpret"))
+                                             "softcap", "scale",
+                                             "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
-                    block_k=None, softcap: float = 0.0,
+                    block_k=None, softcap: float = 0.0, scale=None,
                     interpret: bool = False):
-    """q: (B, S, H, D); k/v: (B, S, Kv, Dv).  Returns (B, S, H, Dv).
+    """q, k: (B, S, H | Kv, D); v: (B, S, Kv, Dv).  Returns (B, S, H, Dv).
 
+    ``scale`` multiplies the scores q k^T (default ``1/sqrt(D)``).
     ``block_q`` / ``block_k`` override the tiling of every kernel; by
     default ``BLOCKS``, cut to divisors of S.
     """
     S = q.shape[1]
     blocks = (_pick_block(S, block_q or BLOCKS[0]),
               _pick_block(S, block_k or BLOCKS[1]))
-    return _flash(q, k, v, causal, softcap, blocks, interpret)
+    return _flash(q, k, v, causal, softcap, scale, blocks, interpret)
 

@@ -185,8 +185,9 @@ def _rope_dims(cfg: ArchConfig) -> int:
 
 
 def use_flash_kernel(q, k, softcap: float) -> bool:
-    """Whether ``gqa_apply`` runs the Pallas flash-attention kernel
-    (forward and backward) in place of ``L.blocked_attention``.
+    """Whether ``gqa_apply`` and ``mla_apply`` run the Pallas
+    flash-attention kernel (forward and backward) in place of
+    ``L.blocked_attention``.
 
     Only where the kernel covers the case on one device: the backend is
     TPU, self-attention (Sq == Sk, so no ``kv_override``), S a multiple
@@ -210,11 +211,13 @@ def gqa_apply(x, p, cfg: ArchConfig, *, positions: jax.Array,
     q, k, v = _qkv(x, p, cfg)
     rd = _rope_dims(cfg)
     if rd and kv_override is None:
-        cos, sin = L.rope_angles(positions, rd, cfg.rope_theta)
+        cos, sin = L.rope_angles(positions, rd, cfg.rope_theta,
+                                 cfg.rope_scaling)
         q = L.apply_rope(q, cos, sin, rd)
         k = L.apply_rope(k, cos, sin, rd)
     elif rd:
-        cos, sin = L.rope_angles(positions, rd, cfg.rope_theta)
+        cos, sin = L.rope_angles(positions, rd, cfg.rope_theta,
+                                 cfg.rope_scaling)
         q = L.apply_rope(q, cos, sin, rd)
     if kv_override is not None:   # cross-attention: encoder / media KV
         k, v = kv_override
@@ -257,7 +260,8 @@ def gqa_decode(x, p, cfg: ArchConfig, k_cache, v_cache, pos):
     rd = _rope_dims(cfg)
     if rd:
         posv = jnp.asarray(pos)[None]
-        cos, sin = L.rope_angles(posv, rd, cfg.rope_theta)
+        cos, sin = L.rope_angles(posv, rd, cfg.rope_theta,
+                                 cfg.rope_scaling)
         q = L.apply_rope(q, cos, sin, rd)
         k = L.apply_rope(k, cos, sin, rd)
     k_cache = jax.lax.dynamic_update_slice_in_dim(
@@ -318,15 +322,29 @@ def _mla_qc(x, p, cfg: ArchConfig, positions):
     c_kv = jnp.einsum("bsd,dc->bsc", x, p["w_dkv"])
     c_kv = L.rmsnorm(c_kv, p["kv_norm"]["w"], cfg.norm_eps)
     k_rope = jnp.einsum("bsd,dr->bsr", x, p["w_krope"])[:, :, None, :]
-    cos, sin = L.rope_angles(positions, m.qk_rope_dim, cfg.rope_theta)
+    cos, sin = L.rope_angles(positions, m.qk_rope_dim, cfg.rope_theta,
+                             cfg.rope_scaling)
     q_rope = L.apply_rope(q_rope, cos, sin, m.qk_rope_dim)
     k_rope = L.apply_rope(k_rope, cos, sin, m.qk_rope_dim)
     return q_nope, q_rope, c_kv, k_rope[:, :, 0, :]
 
 
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """``1/sqrt(qk dims)``, times ``mscale(factor, mscale_all_dim)**2``
+    under YaRN (DeepSeek-V2's attention temperature)."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    r = cfg.rope_scaling
+    if r is not None and r.mscale_all_dim:
+        scale *= L.yarn_mscale(r.factor, r.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_apply(x, p, cfg: ArchConfig, *, positions, causal: bool = True,
               block_q: int = 512, block_k: int = 1024):
-    """Expanded (train/prefill) MLA: materialize per-head K,V from c_kv."""
+    """Expanded (train/prefill) MLA: materialize per-head K,V from c_kv.
+    On one TPU (``use_flash_kernel``) the flash kernel takes q and k at
+    qk_nope + qk_rope dims and v at v_head_dim."""
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
@@ -342,11 +360,15 @@ def mla_apply(x, p, cfg: ArchConfig, *, positions, causal: bool = True,
     q = shard(q, "batch", None, "heads", None)
     k = shard(k, "batch", None, "heads", None)
     v = shard(v, "batch", None, "heads", None)
+    scale = mla_softmax_scale(cfg)
     if S * S <= 1024 * 1024:
-        o = L.full_attention(q, k, v, causal=causal)
+        o = L.full_attention(q, k, v, causal=causal, scale=scale)
+    elif use_flash_kernel(q, k, 0.0):
+        with jax.named_scope("flash_attention"):
+            o = flash_attention(q, k, v, causal=causal, scale=scale)
     else:
-        o = L.blocked_attention(q, k, v, causal=causal,
-                                block_q=block_q, block_k=block_k)
+        o = L.blocked_attention(q, k, v, causal=causal, block_q=block_q,
+                                block_k=block_k, scale=scale)
     o = o.reshape(B, S, -1)
     return jnp.einsum("bsh,hd->bsd", o, p["wo"])
 
@@ -392,7 +414,7 @@ def mla_decode(x, p, cfg: ArchConfig, ckv_cache, krope_cache, pos):
                     ckv_cache.astype(jnp.float32))
          + jnp.einsum("bqhr,bsr->bhqs", q_rope.astype(jnp.float32),
                       krope_cache.astype(jnp.float32)))
-    s = s / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    s = s * mla_softmax_scale(cfg)
     valid = jnp.arange(ckv_cache.shape[1])[None, None, None, :] < pos + 1
     s = jnp.where(valid, s, -1e30)
     prob = jax.nn.softmax(s, axis=-1)

@@ -1,44 +1,74 @@
-"""FFN variants: dense MLP and expert-parallel token-choice MoE.
+"""FFN variants: dense MLP and a dropless token-choice expert layer.
 
-The MoE layer is the framework's EP showcase: experts are sharded over the
-'model' mesh axis via shard_map; tokens stay on their data shard (replicated
-over 'model'), each model shard runs its local experts on a capacity-bounded
-buffer built by scatter (no (T,E,C) one-hot dispatch tensor — that would be
-~100x the token bytes at 32k prefill), and expert outputs are combined with a
-single psum over 'model'.  Differentiable end-to-end (scatter-add / gather /
-psum all have transposes), so it trains under grad-accumulation + remat.
+The expert layer holds ``n_held`` of the routed experts, from index
+``first_held`` (all of them by default).  The router keeps its published
+width: every token is scored against every expert and routed to its
+top-k.  The (token, expert) pairs that land on a held expert are sorted
+by expert and run through the grouped matmul (``kernels.grouped_matmul``)
+for gate, up and down; each result is added back into its token's row,
+weighted by its gate.  No pair is dropped at any skew: the sorted buffer
+has a row for every pair, and the kernel visits only the rows routed to
+held experts.  Pairs routed to experts held elsewhere add nothing here;
+on such a share the gates pass no gradient to the router, which learns
+from the balance loss alone.
+
+Under a mesh with an ``expert`` rule the held experts are split over
+that axis with ``shard_map``: shard ``i`` runs the same layer on its
+slice, the experts from ``first_held + i * count``, and the outputs are
+summed with one psum.  Differentiable end to end.
+
+``moe_apply`` runs under the caller's ``mlp`` scope, in the scopes
+``moe_router``, ``moe_dispatch``, ``moe_experts``, ``moe_combine`` and
+``moe_shared``, and returns beside the output the layer's ``stats``: the
+balance loss ``aux``, ``moe_held_pairs`` (pairs routed to held experts)
+and ``moe_max_expert_pairs`` (the largest load on one held expert).
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, MoEConfig
+from repro.kernels.grouped_matmul import grouped_matmul
 from repro.models import layers as L
 from repro import parallel as PX
-from repro.sharding import batch_axes, current_rules, shard
+from repro.sharding import batch_axes, current_rules
+
+Stats = Dict[str, jax.Array]
+MAX_COUNTER = "moe_max_expert_pairs"
+
+
+def zero_stats(cfg: ArchConfig) -> Stats:
+    """What a layer without experts adds to the model's stats."""
+    stats = {"aux": jnp.zeros((), jnp.float32)}
+    if cfg.moe is not None:
+        stats["moe_held_pairs"] = jnp.zeros((), jnp.int32)
+        stats[MAX_COUNTER] = jnp.zeros((), jnp.int32)
+    return stats
+
+
+def merge_stats(a: Stats, b: Stats) -> Stats:
+    """Two layers' (or microbatches') stats as one: the largest load is
+    the larger, everything else adds up."""
+    return {k: jnp.maximum(a[k], b[k]) if k == MAX_COUNTER else a[k] + b[k]
+            for k in a}
 
 
 def moe_init(key, cfg: ArchConfig, dtype=L.DEFAULT_DTYPE):
     m = cfg.moe
     d = cfg.d_model
-    E = m.n_experts_padded
     ks = jax.random.split(key, 5)
     p = {
-        "router": L.dense_init(ks[0], d, E, dtype=jnp.float32,
-                               scale=0.02),
-        "w_gate": _expert_init(ks[1], E, d, m.d_ff, dtype),
-        "w_up": _expert_init(ks[2], E, d, m.d_ff, dtype),
-        "w_down": _expert_init(ks[3], E, m.d_ff, d, dtype),
+        "router": L.dense_init(ks[0], d, m.n_experts_padded,
+                               dtype=jnp.float32, scale=0.02),
+        "w_gate": _expert_init(ks[1], m.n_experts_held, d, m.d_ff, dtype),
+        "w_up": _expert_init(ks[2], m.n_experts_held, d, m.d_ff, dtype),
+        "w_down": _expert_init(ks[3], m.n_experts_held, m.d_ff, d, dtype),
     }
-    if m.n_routed < E:
-        # padded experts: router column bias -inf'ish via 0-init rows is not
-        # enough; we mask their logits in apply using n_routed.
-        pass
     if m.n_shared > 0:
         p["shared"] = L.mlp_init(ks[4], d, m.n_shared * m.d_ff, "silu", dtype)
     return p
@@ -63,73 +93,135 @@ def moe_logical_axes(cfg: ArchConfig):
     return p
 
 
-def _capacity(n_tokens: int, m: MoEConfig) -> int:
-    c = int(math.ceil(n_tokens * m.top_k / m.n_experts_padded
-                      * m.capacity_factor))
-    return max(8, -(-c // 8) * 8)   # round up to 8
+def _route(x, router_w, m: MoEConfig):
+    """Scores of every expert and each token's top-k.  x: (B, S, D).
+
+    Returns the gates and experts of the top-k, (T, k), and the balance
+    loss (plus the router z-loss where the config has one)."""
+    B, S, D = x.shape
+    E = m.n_experts_padded
+    logits = jnp.einsum("td,de->te", x.reshape(B * S, D).astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if m.n_routed < E:            # mask padded experts out of routing
+        logits = jnp.where(jnp.arange(E)[None, :] < m.n_routed, logits,
+                           -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, m.top_k)          # (T, k)
+
+    # balance loss: sum over experts of (share of the top-k picks) x
+    # (mean score), both scaled to 1 when uniform, per sequence
+    # (``seq_aux``) or over the whole batch
+    groups = B if m.seq_aux else 1
+    picks = jax.nn.one_hot(top_e.reshape(groups, -1), E, dtype=jnp.float32)
+    share = jnp.sum(picks, axis=1) * (E / picks.shape[1])  # (groups, E)
+    score = jnp.mean(probs.reshape(groups, -1, E), axis=1)
+    aux = jnp.mean(jnp.sum(share * score, axis=-1)) * m.aux_coef
+    if m.router_z_coef:
+        aux = aux + m.router_z_coef * jnp.mean(
+            jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    return top_p, top_e, aux
+
+
+@jax.custom_vjp
+def _take_rows(x, idx, inv):
+    """``x[idx]`` for a permutation ``idx`` with inverse ``inv``; the
+    gradient is a gather by ``inv``, not a scatter-add."""
+    return jnp.take(x, idx, axis=0)
+
+
+def _take_rows_fwd(x, idx, inv):
+    return jnp.take(x, idx, axis=0), (idx, inv)
+
+
+def _take_rows_bwd(res, g):
+    idx, inv = res
+    return jnp.take(g, inv, axis=0), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _pair_rows(x, order, inv, k):
+    """The sorted pairs' rows: row ``order[j] // k`` of ``x`` (T, D) for
+    pair j, each token's row once for each of its k picks, without a
+    k-fold copy of ``x``.  The gradient gathers by ``inv`` and sums each
+    token's k rows: no scatter."""
+    return jnp.take(x, order // k, axis=0)
+
+
+def _pair_rows_fwd(x, order, inv, k):
+    return _pair_rows(x, order, inv, k), inv
+
+
+def _pair_rows_bwd(k, inv, g):
+    g = jnp.take(g, inv, axis=0)
+    return jnp.sum(g.reshape(-1, k, g.shape[-1]), axis=1), None, None
+
+
+_pair_rows.defvjp(_pair_rows_fwd, _pair_rows_bwd)
+
+
+def _held_experts(x2, top_p, top_e, w_gate, w_up, w_down, first):
+    """The held experts' part of the layer.  x2: (T, D); top_p, top_e:
+    (T, k); w_*: the ``count`` experts from index ``first``.
+
+    Returns (T, D) f32 and the pairs routed to each held expert."""
+    T, D = x2.shape
+    k = top_e.shape[1]
+    count = w_gate.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        local = top_e - first
+        held = (local >= 0) & (local < count)                 # (T, k)
+        key = jnp.where(held, local, count).reshape(-1)       # (T*k,)
+        # a stable sort by expert, the pairs of other experts last; its
+        # inverse by counting (no scatter): a pair's place is the pairs
+        # of lower experts plus the earlier pairs of its own
+        order = jnp.argsort(key, stable=True)
+        onehot = key[:, None] == jnp.arange(count + 1)[None, :]
+        sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)      # (count+1,)
+        before = jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - 1
+        starts = jnp.cumsum(sizes) - sizes
+        inv = jnp.sum(jnp.where(onehot, before + starts[None, :], 0),
+                      axis=1).astype(order.dtype)
+        xs = _pair_rows(x2, order, inv, k)
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(grouped_matmul(xs, w_gate, sizes)) \
+            * grouped_matmul(xs, w_up, sizes)
+        ys = grouped_matmul(h, w_down, sizes)    # rows past the held: 0
+    with jax.named_scope("moe_combine"):
+        y = _take_rows(ys, inv, order).reshape(T, k, D)
+        gate = jnp.where(held, top_p, 0.0)
+        out = jnp.sum(y.astype(jnp.float32) * gate[..., None], axis=1)
+    return out, sizes[:count]
 
 
 def _moe_local(x, router_w, w_gate, w_up, w_down, *, m: MoEConfig,
-               shard_idx, model_axis: Optional[str]):
-    """Per-shard MoE.  x: (B_local, S, D); expert weights: local slice."""
+               first, model_axis: Optional[str]):
+    """One shard's expert layer.  x: (B_local, S, D); expert weights:
+    the shard's ``count`` experts from index ``first``.  Returns the
+    output, the balance loss and the pairs routed to each of its
+    experts from its tokens."""
     B, S, D = x.shape
-    T = B * S
-    E = m.n_experts_padded
-    E_loc = w_gate.shape[0]
-    k = m.top_k
-    C = _capacity(T, m)
-
-    x2 = x.reshape(T, D)
-    logits = jnp.einsum("td,de->te", x2.astype(jnp.float32),
-                        router_w.astype(jnp.float32))
-    if m.n_routed < E:            # mask padded experts out of routing
-        pad_mask = jnp.arange(E) < m.n_routed
-        logits = jnp.where(pad_mask[None, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)               # (T,k)
-
-    # --- capacity assignment (global over E, shared across model shards) ---
-    flat_e = top_e.reshape(-1)                            # (T*k,)
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)   # (T*k, E)
-    pos_in_e = jnp.cumsum(onehot, axis=0) * onehot
-    slot = jnp.sum(pos_in_e, axis=1) - 1                  # (T*k,)
-    slot = slot.reshape(T, k)
-    keep = slot < C
-
-    e0 = shard_idx * E_loc
-    local = (top_e >= e0) & (top_e < e0 + E_loc) & keep   # (T,k)
-    b_idx = jnp.where(local, top_e - e0, 0)
-    s_idx = jnp.where(local, slot, C)                     # C row = dropped
-
-    buf = jnp.zeros((E_loc, C + 1, D), x.dtype)
-    for j in range(k):            # k small (<=6): k scatters, no token repeat
-        buf = buf.at[b_idx[:, j], s_idx[:, j]].add(
-            x2 * local[:, j, None].astype(x.dtype))
-
-    h = jnp.einsum("ecd,edf->ecf", buf, w_gate)
-    u = jnp.einsum("ecd,edf->ecf", buf, w_up)
-    y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(h) * u, w_down)
-
-    out = jnp.zeros((T, D), jnp.float32)
-    for j in range(k):
-        contrib = y[b_idx[:, j], s_idx[:, j]].astype(jnp.float32)
-        gate = (top_p[:, j] * local[:, j]).astype(jnp.float32)
-        out = out + contrib * gate[:, None]
+    with jax.named_scope("moe_router"):
+        top_p, top_e, aux = _route(x, router_w, m)
+        if m.n_experts_held < m.n_routed:
+            # one chip's share: the experts held elsewhere add nothing
+            # here, so the gates' gradient would see only the held ones
+            # and drive the router towards or away from them all; the
+            # router learns from the balance loss alone
+            top_p = jax.lax.stop_gradient(top_p)
+    out, sizes = _held_experts(x.reshape(B * S, D), top_p, top_e,
+                               w_gate, w_up, w_down, first)
     if model_axis is not None:
-        out = PX.psum(out, model_axis)
-
-    # --- aux losses (identical on every model shard; local-token means) ----
-    dispatch_frac = jnp.mean(
-        jax.nn.one_hot(top_e, E, dtype=jnp.float32), axis=(0, 1)) * E
-    mean_prob = jnp.mean(probs, axis=0) * E
-    aux = jnp.mean(dispatch_frac * mean_prob) * m.aux_coef
-    zl = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-    aux = aux + m.router_z_coef * zl
-    return out.reshape(B, S, D).astype(x.dtype), aux
+        with jax.named_scope("moe_combine"):
+            out = PX.psum(out, model_axis)
+    return out.reshape(B, S, D).astype(x.dtype), aux, sizes
 
 
-def moe_apply(x, p, cfg: ArchConfig) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) -> (out, aux_loss).  Shared experts added densely."""
+def moe_apply(x, p, cfg: ArchConfig) -> Tuple[jax.Array, Stats]:
+    """x: (B, S, D) -> (out, stats).  Shared experts added densely."""
     m = cfg.moe
     rules = current_rules()
     model_ax = None
@@ -138,35 +230,43 @@ def moe_apply(x, p, cfg: ArchConfig) -> Tuple[jax.Array, jax.Array]:
         if ma is not None:
             model_ax = ma if isinstance(ma, str) else ma[0]
 
-    if model_ax is None:          # single-shard path (tests, CPU)
-        out, aux = _moe_local(x, p["router"], p["w_gate"], p["w_up"],
-                              p["w_down"], m=m, shard_idx=0, model_axis=None)
+    if model_ax is None:          # single-shard path (one chip, CPU)
+        out, aux, sizes = _moe_local(
+            x, p["router"], p["w_gate"], p["w_up"], p["w_down"], m=m,
+            first=m.first_held, model_axis=None)
+        pairs, most = jnp.sum(sizes), jnp.max(sizes)
     else:
         mesh = rules.mesh
         from jax.sharding import PartitionSpec as P
         bspec = rules.rules.get("batch")
         n_model = mesh.shape[model_ax]
-        assert m.n_experts_padded % n_model == 0, (
-            f"experts {m.n_experts_padded} must divide model axis {n_model}")
+        assert m.n_experts_held % n_model == 0, (
+            f"experts {m.n_experts_held} must divide model axis {n_model}")
+        count = m.n_experts_held // n_model
 
         def mapped(xl, rw, wg, wu, wd):
-            idx = PX.axis_index(model_ax)
-            out, aux = _moe_local(xl, rw, wg, wu, wd, m=m, shard_idx=idx,
-                                  model_axis=model_ax)
-            # aux identical across model shards; average over batch shards
+            first = m.first_held + PX.axis_index(model_ax) * count
+            out, aux, sizes = _moe_local(
+                xl, rw, wg, wu, wd, m=m, first=first, model_axis=model_ax)
+            # aux is identical across model shards: average it over batch
+            # shards; each expert's load sums over them
             for ax in batch_axes(rules):
                 aux = PX.pmean(aux, ax)
-            return out, aux
+                sizes = PX.psum(sizes, ax)
+            pairs = PX.psum(jnp.sum(sizes), model_ax)
+            most = PX.pmax(jnp.max(sizes), model_ax)
+            return out, aux, pairs, most
 
-        out, aux = PX.shard_map(
+        out, aux, pairs, most = PX.shard_map(
             mapped, mesh=mesh,
             in_specs=(P(bspec, None, None), P(None, None),
                       P(model_ax, None, None), P(model_ax, None, None),
                       P(model_ax, None, None)),
-            out_specs=(P(bspec, None, None), P()),
+            out_specs=(P(bspec, None, None), P(), P(), P()),
             check_vma=False,
         )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if m.n_shared > 0:
-        out = out + L.mlp_apply(x, p["shared"], "silu")
-    return out, aux
+        with jax.named_scope("moe_shared"):
+            out = out + L.mlp_apply(x, p["shared"], "silu")
+    return out, {"aux": aux, "moe_held_pairs": pairs, MAX_COUNTER: most}

@@ -79,13 +79,55 @@ def norm_init(d: int, kind: str, dtype=jnp.float32):
 # rotary embeddings (partial-rotary supported)
 # ---------------------------------------------------------------------------
 
-def rope_angles(positions: jax.Array, rope_dim: int,
-                theta: float) -> Tuple[jax.Array, jax.Array]:
-    """positions: (...,) int -> cos/sin of shape (..., rope_dim//2)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (DeepSeek-V2's
+    ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_correction_dim(rotations: float, dim: int, theta: float,
+                         max_pos: int) -> float:
+    """The rotary dimension whose wavelength fits ``rotations`` turns
+    into ``max_pos`` positions."""
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def rope_inv_freq(rope_dim: int, theta: float, scaling=None) -> jax.Array:
+    """Inverse frequencies (rope_dim//2,).  With a YaRN ``scaling``
+    (``configs.base.YaRNConfig``) the high frequencies, which turn more
+    than ``beta_fast`` times over the original context, keep their value
+    (extrapolation), those turning fewer than ``beta_slow`` times are
+    divided by ``factor`` (interpolation), and a linear ramp over the
+    dimensions between blends the two."""
     half = rope_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if scaling is None or scaling.factor <= 1.0:
+        return freqs
+    n = scaling.original_max_position_embeddings
+    low = max(math.floor(_yarn_correction_dim(
+        scaling.beta_fast, rope_dim, theta, n)), 0)
+    high = min(math.ceil(_yarn_correction_dim(
+        scaling.beta_slow, rope_dim, theta, n)), rope_dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+
+
+def rope_angles(positions: jax.Array, rope_dim: int, theta: float,
+                scaling=None) -> Tuple[jax.Array, jax.Array]:
+    """positions: (...,) int -> cos/sin of shape (..., rope_dim//2).
+    ``scaling``: a ``YaRNConfig`` or None (see ``rope_inv_freq``); YaRN
+    also scales cos and sin by ``mscale(mscale) / mscale(mscale_all_dim)``."""
+    freqs = rope_inv_freq(rope_dim, theta, scaling)
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None and scaling.factor > 1.0:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
+    return cos, sin
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
@@ -136,18 +178,20 @@ def _gqa_out(p, v):
 def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool, q_offset: int = 0,
                       block_q: int = 512, block_k: int = 1024,
-                      softcap: float = 0.0) -> jax.Array:
+                      softcap: float = 0.0,
+                      scale: Optional[float] = None) -> jax.Array:
     """Memory-bounded online-softmax attention (pure jnp; flash-style).
 
     q: (B, Sq, H, D); k/v: (B, Sk, Kv, D).  GQA handled by grouped einsum (no
     KV repetition).  The Pallas flash kernel is the TPU production path; this
-    is the XLA fallback / oracle with identical math.
+    is the XLA fallback / oracle with identical math.  ``scale`` multiplies
+    the scores (default ``1/sqrt(D)``).
     """
     B, Sq, H, D = q.shape
     Sk, Kv = k.shape[1], k.shape[2]
     Dv = v.shape[3]
     G = H // Kv
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
 
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
@@ -215,13 +259,17 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def full_attention(q, k, v, *, causal: bool, q_offset: int = 0,
-                   softcap: float = 0.0) -> jax.Array:
+                   softcap: float = 0.0,
+                   scale: Optional[float] = None) -> jax.Array:
     """Unblocked reference attention (small shapes / oracles)."""
     B, Sq, H, D = q.shape
     Sk, Kv = k.shape[1], k.shape[2]
     G = H // Kv
     qg = q.reshape(B, Sq, Kv, G, D)
-    s = _gqa_scores(qg, k) / math.sqrt(D)
+    if scale is None:
+        s = _gqa_scores(qg, k) / math.sqrt(D)
+    else:
+        s = _gqa_scores(qg, k) * scale
     if softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
     if causal:

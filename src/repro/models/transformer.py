@@ -9,7 +9,7 @@ Public surface (shared by all model classes in this package):
     init(rng) -> params
     param_logical_axes() -> pytree of logical-axis tuples (same treedef)
     loss(params, batch) -> (loss, metrics)
-    forward_logits(params, batch) -> logits            (train fwd / prefill)
+    forward_logits(params, batch) -> (logits, stats)   (train fwd / prefill)
     init_cache(batch_size, seq_len) -> cache
     cache_logical_axes(...)
     prefill(params, batch, cache) -> (logits, cache)
@@ -93,8 +93,8 @@ def layer_apply(x, p, cfg: ArchConfig, *, positions, moe: bool,
                 causal: bool = True,
                 media_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
                 cross: bool = False):
-    """Returns (x_out, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+    """Returns (x_out, stats): ``ffn.zero_stats`` or the expert layer's."""
+    stats = F.zero_stats(cfg)
     h = L.norm_apply(x, p["attn_norm"], cfg.norm, cfg.norm_eps)
     h = shard(h, "batch", None, None)
     with jax.named_scope("attention"):
@@ -108,24 +108,17 @@ def layer_apply(x, p, cfg: ArchConfig, *, positions, moe: bool,
         else:
             a = A.gqa_apply(h, p["attn"], cfg, positions=positions,
                             causal=causal)
-    if cfg.parallel_block:
-        if moe:
-            f, aux = F.moe_apply(h, p["ffn"], cfg)
-        else:
-            with jax.named_scope("mlp"):
-                f = L.mlp_apply(h, p["ffn"], cfg.act)
-        x = x + a + f
-    else:
+    if not cfg.parallel_block:
         x = x + a
-        h2 = L.norm_apply(x, p["ffn_norm"], cfg.norm, cfg.norm_eps)
-        h2 = shard(h2, "batch", None, None)
+        h = L.norm_apply(x, p["ffn_norm"], cfg.norm, cfg.norm_eps)
+        h = shard(h, "batch", None, None)
+    with jax.named_scope("mlp"):
         if moe:
-            f, aux = F.moe_apply(h2, p["ffn"], cfg)
+            f, stats = F.moe_apply(h, p["ffn"], cfg)
         else:
-            with jax.named_scope("mlp"):
-                f = L.mlp_apply(h2, p["ffn"], cfg.act)
-        x = x + f
-    return shard(x, "batch", "seq", None), aux
+            f = L.mlp_apply(h, p["ffn"], cfg.act)
+    x = x + a + f if cfg.parallel_block else x + f
+    return shard(x, "batch", "seq", None), stats
 
 
 # ---------------------------------------------------------------------------
@@ -233,15 +226,14 @@ class TransformerLM:
         cfg = self.cfg
 
         def body(carry, lp):
-            xc, aux = carry
-            xo, a = layer_apply(xc, lp, cfg, positions=positions, moe=moe,
+            xc, stats = carry
+            xo, s = layer_apply(xc, lp, cfg, positions=positions, moe=moe,
                                 causal=causal)
-            return (xo, aux + a), None
+            return (xo, F.merge_stats(stats, s)), None
 
         f = jax.checkpoint(body) if self.remat else body
-        (x, aux), _ = jax.lax.scan(f, (x, jnp.zeros((), jnp.float32)),
-                                   stacked)
-        return x, aux
+        (x, stats), _ = jax.lax.scan(f, (x, F.zero_stats(cfg)), stacked)
+        return x, stats
 
     def _vlm_apply(self, x, blocks, *, positions, media):
         cfg = self.cfg
@@ -252,17 +244,17 @@ class TransformerLM:
         media = shard(media, "batch", None, None)
 
         def super_body(carry, sp):
-            xc, aux = carry
+            xc, stats = carry
 
             def self_body(c, lp):
                 xs, a0 = c
                 xo, a = layer_apply(xs, lp, cfg, positions=positions,
                                     moe=False)
-                return (xo, a0 + a), None
+                return (xo, F.merge_stats(a0, a)), None
 
             if self.remat:        # per-layer remat: one layer's gathered
                 self_body = jax.checkpoint(self_body)  # weights live at once
-            (xc, aux), _ = jax.lax.scan(self_body, (xc, aux), sp["self"])
+            (xc, stats), _ = jax.lax.scan(self_body, (xc, stats), sp["self"])
             # cross layer: media K/V projected by this layer's wk/wv
             pm = sp["cross"]
             B, M, _ = media.shape
@@ -273,12 +265,11 @@ class TransformerLM:
                 B, M, cfg.n_kv_heads, hd)
             xc, a = layer_apply(xc, pm, cfg, positions=positions, moe=False,
                                 cross=True, media_kv=(mk, mv))
-            return (xc, aux + a), None
+            return (xc, F.merge_stats(stats, a)), None
 
         f = jax.checkpoint(super_body) if self.remat else super_body
-        (x, aux), _ = jax.lax.scan(f, (x, jnp.zeros((), jnp.float32)),
-                                   blocks)
-        return x, aux
+        (x, stats), _ = jax.lax.scan(f, (x, F.zero_stats(cfg)), blocks)
+        return x, stats
 
     def _encode(self, params, frames):
         """Whisper encoder over stubbed frame embeddings (B, F, D)."""
@@ -296,7 +287,7 @@ class TransformerLM:
         cfg = self.cfg
 
         def body(carry, lp):
-            xc, aux = carry
+            xc, stats = carry
             block, xattn, xnorm = lp
             xo, a = layer_apply(xc, block, cfg, positions=positions,
                                 moe=False)
@@ -310,17 +301,18 @@ class TransformerLM:
                 B, M, cfg.n_kv_heads, hd)
             c = A.gqa_apply(h, xattn, cfg, positions=positions,
                             kv_override=(mk, mv))
-            return (xo + c, aux + a), None
+            return (xo + c, F.merge_stats(stats, a)), None
 
         f = jax.checkpoint(body) if self.remat else body
-        (x, aux), _ = jax.lax.scan(
-            f, (x, jnp.zeros((), jnp.float32)),
+        (x, stats), _ = jax.lax.scan(
+            f, (x, F.zero_stats(cfg)),
             (params["blocks"], params["cross_blocks"],
              params["cross_norms"]))
-        return x, aux
+        return x, stats
 
-    def forward_logits(self, params, batch) -> Tuple[jax.Array, jax.Array]:
-        """Returns (logits, aux)."""
+    def forward_logits(self, params, batch) -> Tuple[jax.Array, F.Stats]:
+        """Returns (logits, stats): the balance loss ``aux`` and, with
+        experts, their counters (``ffn.moe_apply``), over all layers."""
         cfg = self.cfg
         tokens = batch["tokens"]
         # gather through f32 so the backward scatter-add (the embed
@@ -334,32 +326,32 @@ class TransformerLM:
                 tokens.shape[1], cfg.d_model).astype(x.dtype)[None]
         x = shard(x, "batch", None, None)
         positions = jnp.arange(tokens.shape[1])
-        aux = jnp.zeros((), jnp.float32)
+        stats = F.zero_stats(cfg)
 
         if cfg.is_encdec:
             x = x + L.sinusoidal_positions(
                 tokens.shape[1], cfg.d_model).astype(x.dtype)[None]
             enc_out = self._encode(params, batch["frames"])
-            x, aux = self._decoder_encdec(params, x, positions, enc_out)
+            x, stats = self._decoder_encdec(params, x, positions, enc_out)
         elif cfg.family == "vlm":
-            x, aux = self._vlm_apply(x, params["blocks"],
-                                     positions=positions,
-                                     media=batch["media"])
+            x, stats = self._vlm_apply(x, params["blocks"],
+                                       positions=positions,
+                                       media=batch["media"])
         else:
             if "first" in params:
-                x, a0 = self._stack_apply(x, params["first"],
+                x, s0 = self._stack_apply(x, params["first"],
                                           positions=positions, moe=False)
-                aux = aux + a0
-            x, a1 = self._stack_apply(x, params["blocks"],
+                stats = F.merge_stats(stats, s0)
+            x, s1 = self._stack_apply(x, params["blocks"],
                                       positions=positions,
                                       moe=cfg.moe is not None)
-            aux = aux + a1
+            stats = F.merge_stats(stats, s1)
 
         with jax.named_scope("head"):
             x = L.norm_apply(x, params["final_norm"], cfg.norm,
                              cfg.norm_eps)
             logits = self._logits(params, x)
-        return logits, aux
+        return logits, stats
 
     def _logits(self, params, x):
         # f32 accumulation: the loss consumes logits in f32 anyway, and
@@ -374,11 +366,17 @@ class TransformerLM:
         return shard(logits, "batch", None, "vocab")
 
     def loss(self, params, batch):
-        logits, aux = self.forward_logits(params, batch)
+        """(loss, metrics); ``metrics["counters"]`` holds the expert
+        layer's integer counters (none without experts)."""
+        logits, stats = self.forward_logits(params, batch)
         with jax.named_scope("head"):
             nll, zl = L.softmax_xent(logits, batch["targets"])
-        total = nll + zl + aux
-        return total, {"nll": nll, "z_loss": zl, "aux": aux}
+        aux = stats.pop("aux")
+        return nll + zl + aux, {"nll": nll, "z_loss": zl, "aux": aux,
+                                "counters": stats}
+
+    # two microbatches' ``counters`` as one step's
+    merge_counters = staticmethod(F.merge_stats)
 
     # ------------------------------------------------------------- decode
     def init_cache(self, batch_size: int, seq_len: int):

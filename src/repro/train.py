@@ -82,8 +82,20 @@ def _split_micro(batch: Dict[str, jax.Array], accum: int):
     return {k: f(v) for k, v in batch.items()}
 
 
+def _merge_micro(model, counts, accum: int):
+    """One step's counters from its microbatches' (stacked on axis 0),
+    merged by the model's own ``merge_counters``."""
+    if not counts:
+        return {}
+    parts = [jax.tree.map(lambda c: c[i], counts) for i in range(accum)]
+    return functools.reduce(model.merge_counters, parts)
+
+
 def make_loss_and_grad(model, *, accum: int):
-    """Pod-local accumulated (loss, grads) over ``accum`` microbatches.
+    """Pod-local accumulated (loss, grads, counters) over ``accum``
+    microbatches; ``counters`` is the model's ``metrics["counters"]``
+    (empty where it has none), merged over microbatches by its
+    ``merge_counters``.
 
     Differentiates wrt an f32 view of the params (cast back to their
     storage dtype inside the loss, so the forward math is unchanged):
@@ -113,17 +125,18 @@ def make_loss_and_grad(model, *, accum: int):
 
         def step(carry, mb):
             loss_sum, grads = carry
-            (loss, _metrics), g = jax.value_and_grad(
+            (loss, metrics), g = jax.value_and_grad(
                 cast_loss, has_aux=True)(params32, mb)
             grads = jax.tree.map(lambda a, b: a + b, grads, g)
-            return (loss_sum + loss, grads), None
+            return (loss_sum + loss, grads), metrics.get("counters", {})
 
         zero_g = jax.tree.map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        (loss_sum, grads), _ = jax.lax.scan(
+        (loss_sum, grads), counts = jax.lax.scan(
             step, (jnp.zeros((), jnp.float32), zero_g), micro)
         inv = 1.0 / accum
-        return loss_sum * inv, jax.tree.map(lambda g: g * inv, grads)
+        return (loss_sum * inv, jax.tree.map(lambda g: g * inv, grads),
+                _merge_micro(model, counts, accum))
 
     return fn
 
@@ -303,7 +316,7 @@ def _make_manual_sync_step(model, ocfg: optim.AdamWConfig, *, accum: int,
                                   deterministic=dt)
 
     def hier_rank(params, batch):
-        loss, grads = lg(params, batch)
+        loss, grads, _ = lg(params, batch)
         if sync_axes:
             grads = jax.tree.map(
                 lambda g: hier_all_reduce_mean(
@@ -465,6 +478,10 @@ def make_train_step(model, ocfg: optim.AdamWConfig, *, accum: int = 1,
                     deterministic_reduce: bool = False):
     """Returns step(params, opt_state, batch) -> (params, opt, metrics).
 
+    In the ``xla`` and ``compressed`` modes ``metrics["counters"]`` holds
+    the model's counters (``make_loss_and_grad``), which
+    ``Trainer.history`` copies; the manual-sync modes report none.
+
     ``overlap=True`` (bucketed modes only) software-pipelines the
     per-bucket hierarchical sync: bucket i+1's fast-axis reduce-scatter
     is issued under bucket i's slow hop.  Bitwise-identical losses; a
@@ -527,9 +544,9 @@ def make_train_step(model, ocfg: optim.AdamWConfig, *, accum: int = 1,
     lg = make_loss_and_grad(model, accum=accum)
 
     def base_step(params, opt_state, batch):
-        loss, grads = lg(params, batch)
+        loss, grads, counters = lg(params, batch)
         params, opt_state, om = optim.apply(ocfg, params, grads, opt_state)
-        metrics = {"loss": loss, **om}
+        metrics = {"loss": loss, **om, "counters": counters}
         return params, opt_state, metrics
 
     return base_step
@@ -875,7 +892,9 @@ class Trainer:
                             self.history.append(
                                 {"step": step,
                                  "loss": float(metrics["loss"]),
-                                 "sec_per_step": dt})
+                                 "sec_per_step": dt,
+                                 **{k: int(v) for k, v in jax.device_get(
+                                     metrics.get("counters", {})).items()}})
                     if (step + 1) % tcfg.ckpt_every == 0:
                         with tracing.span("train.ckpt"):
                             pending = self._save(pending, step + 1,

@@ -96,7 +96,9 @@ def test_hierarchical_allreduce_correct_multidevice():
 
 
 def test_moe_sharded_matches_single_device():
-    """EP shard_map MoE == single-shard MoE on identical inputs."""
+    """EP shard_map MoE == single-shard MoE on identical inputs: the
+    dropless expert layer, each of the 4 model shards holding 2 of the 8
+    experts (``first = shard * 2``)."""
     out = run_multidevice("""
         from repro import parallel as PX
         import jax, jax.numpy as jnp, numpy as np
@@ -113,19 +115,24 @@ def test_moe_sharded_matches_single_device():
         x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model),
                               jnp.float32).astype(jnp.bfloat16)
 
-        ref, aux_ref = F.moe_apply(x, p, cfg)          # no rules: 1 shard
+        ref, st_ref = F.moe_apply(x, p, cfg)           # no rules: 1 shard
 
         with mesh:
             with use_rules(rules):
                 xs = jax.device_put(x, NamedSharding(
                     mesh, P("data", None, None)))
-                out, aux = jax.jit(
+                out, st = jax.jit(
                     lambda x, p: F.moe_apply(x, p, cfg))(xs, p)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             rtol=3e-2, atol=3e-2)
-        np.testing.assert_allclose(float(aux), float(aux_ref),
+        np.testing.assert_allclose(float(st["aux"]), float(st_ref["aux"]),
                                    rtol=1e-2, atol=1e-4)
+        # dropless: every one of the 4 x 16 tokens' top-k pairs is held
+        assert int(st["moe_held_pairs"]) == int(st_ref["moe_held_pairs"]) \
+            == 4 * 16 * cfg.moe.top_k
+        assert int(st["moe_max_expert_pairs"]) == \
+            int(st_ref["moe_max_expert_pairs"])
         print("MOE_OK")
         """)
     assert "MOE_OK" in out

@@ -136,8 +136,8 @@ def test_flash_attention_lse_residual():
     q = jax.random.normal(ks[0], (B, S, H, D))
     k = jax.random.normal(ks[1], (B, S, Kv, D))
     v = jax.random.normal(ks[2], (B, S, Kv, D))
-    _, lse = jax.jit(flash_ops._forward, static_argnums=(3, 4, 5, 6))(
-        q, k, v, True, 0.0, (64, 128), True)
+    _, lse = jax.jit(flash_ops._forward, static_argnums=(3, 4, 5, 6, 7))(
+        q, k, v, True, 0.0, None, (64, 128), True)
     kg = jnp.repeat(k, H // Kv, axis=2)
     s = jnp.einsum("bqhd,bshd->bhqs", q, kg,
                    precision=jax.lax.Precision.HIGHEST) / np.sqrt(D)

@@ -1,9 +1,15 @@
 """Per-arch smoke tests (deliverable f): reduced same-family config, one
 forward/train step on CPU, output shapes + no NaNs."""
+import glob
+import json
+import math
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, YaRNConfig
 from repro.models.registry import (ARCH_IDS, build_model, get_config,
                                    reduced_config)
 
@@ -106,3 +112,27 @@ def test_full_configs_match_assignment():
     z = get_config("zamba2-1.2b")
     assert z.ssm.d_state == 64 and z.sub_quadratic
     assert get_config("xlstm-125m").sub_quadratic
+
+
+BENCH_CONFIGS = sorted(glob.glob(os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "chipbench", "configs", "*.json")))
+
+
+@pytest.mark.parametrize("path", BENCH_CONFIGS,
+                         ids=[os.path.basename(p) for p in BENCH_CONFIGS])
+def test_benchmark_configs_build_through_archconfig(path):
+    """Each benchmark config's ``arch`` builds the program's model the
+    way the benchmark's harness does, ``ArchConfig(**arch)``: nested
+    ``moe``, ``mla`` and ``rope_scaling`` groups become their dataclasses
+    and the config stays hashable."""
+    with open(path) as f:
+        arch = json.load(f)["arch"]
+    cfg = ArchConfig(**arch)
+    hash(cfg)
+    for name, cls in (("moe", MoEConfig), ("mla", MLAConfig),
+                      ("rope_scaling", YaRNConfig)):
+        assert (name in arch) == isinstance(getattr(cfg, name), cls)
+    model = build_model(cfg)
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
+    assert sum(math.prod(a.shape) for a in jax.tree.leaves(shapes)) > 1e8

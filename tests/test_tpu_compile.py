@@ -97,6 +97,71 @@ def test_gqa_apply_grad_compiles_to_flash_kernels_for_v5e(one_chip,
     assert "while" not in txt
 
 
+def test_flash_attention_mla_widths_compile_for_v5e(one_chip):
+    """deepseek-v2-lite's expanded MLA (B 4, S 4096, H = Kv 16, q and k
+    192 dims, v 128) with YaRN's scale: forward and both backward
+    kernels at ``ops.BLOCKS`` fit the chip's VMEM."""
+    B, S, H = 4, 4096, 16
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, scale=0.1147).astype(F32)),
+            (0, 1, 2))(q, k, v)
+
+    txt = _compile_text(grads, [((B, S, H, 192), BF16),
+                                ((B, S, H, 192), BF16),
+                                ((B, S, H, 128), BF16)], one_chip)
+    assert txt.count('custom_call_target="tpu_custom_call"') >= 3
+
+
+def test_mla_apply_grad_compiles_to_flash_kernels_for_v5e(one_chip,
+                                                         monkeypatch):
+    """deepseek-v2-lite attention at its published widths and its 4096
+    context: on a TPU ``mla_apply`` and its gradient run the flash
+    kernels, with no blocked-attention loop."""
+    from repro.models import attention as A
+    from repro.models.registry import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("deepseek-v2-lite-16b")
+    B, S = 4, 4096
+    params = jax.eval_shape(lambda key: A.mla_init(key, cfg),
+                            jax.random.key(0))
+    shapes = [((B, S, cfg.d_model), BF16)] + [
+        (w.shape, w.dtype) for w in jax.tree.leaves(params)]
+    treedef = jax.tree.structure(params)
+
+    def grads(x, *leaves):
+        def loss(x, p):
+            out = A.mla_apply(x, p, cfg, positions=jnp.arange(S))
+            return jnp.sum(out.astype(F32))
+        return jax.grad(loss, (0, 1))(
+            x, jax.tree.unflatten(treedef, leaves))
+
+    txt = _compile_text(grads, shapes, one_chip)
+    assert txt.count('custom_call_target="tpu_custom_call"') >= 3
+    assert "while" not in txt
+
+
+@pytest.mark.parametrize("K,N", [(2048, 1408), (1408, 2048)])
+def test_expert_gmm_compiles_for_v5e(one_chip, K, N):
+    """The grouped matmul at deepseek-v2-lite's expert widths (gate/up
+    2048 -> 1408, down 1408 -> 2048) over the cell's dropless buffer of
+    16,384 tokens x top-6 rows and 8 held experts: forward, and the
+    rows' and weights' gradients."""
+    from repro.kernels.grouped_matmul import expert_gmm
+
+    def grads(lhs, rhs, sizes):
+        return jax.grad(lambda a, b: jnp.sum(jnp.square(
+            expert_gmm(a, b, sizes).astype(F32))), (0, 1))(lhs, rhs)
+
+    txt = _compile_text(grads, [((16384 * 6, K), BF16), ((8, K, N), BF16),
+                                ((9,), jnp.int32)], one_chip)
+    assert txt.count('custom_call_target="tpu_custom_call"') >= 3
+    # the kernels' ops carry the names a profile reader looks for
+    assert "%gmm." in txt and "%tgmm." in txt
+
+
 def test_mlstm_compiles_for_v5e(one_chip):
     # xlstm-125m: d_model 768 -> 2x up-projection 1536 over 4 heads
     B, S, H, D = 1, 2048, 4, 384

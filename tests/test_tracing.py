@@ -123,3 +123,63 @@ def test_train_step_hlo_carries_the_scopes(arch, scopes):
              for p in re.sub(r"(jvp|transpose)\(|\)", "",
                              name).split("/")}
     assert scopes | {"rematted_computation"} <= parts
+
+
+def _dsv2_tiny():
+    import dataclasses
+    cfg = reduced_config(get_config("deepseek-v2-lite-16b"))
+    # 4 of the 8 experts held, from expert 2
+    return dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, n_held=4, first_held=2))
+
+
+def test_moe_ops_carry_their_scopes():
+    cfg = _dsv2_tiny()
+    model = build_model(cfg, remat=True)
+    ocfg = optim.AdamWConfig()
+    params, opt_state, _, _ = init_train_state(model, ocfg)
+    step = make_jitted_train_step(model, ocfg, accum=1, rules=None)
+    batch = {"tokens": jnp.zeros((2, 16), jnp.int32),
+             "targets": jnp.zeros((2, 16), jnp.int32)}
+    text = step.lower(params, opt_state, batch).as_text("hlo",
+                                                        debug_info=True)
+    stacks = {re.sub(r"(jvp|transpose)\(|\)", "", name)
+              for name in re.findall(r'op_name="([^"]*)"', text)}
+    for scope in ("moe_router", "moe_dispatch", "moe_experts",
+                  "moe_combine", "moe_shared"):
+        assert any(f"/mlp/{scope}/" in s for s in stacks), scope
+
+
+def test_moe_counters_reach_history(tmp_path, monkeypatch):
+    """``moe_held_pairs`` and ``moe_max_expert_pairs`` of each step, in
+    ``Trainer.history``, against a plain count over every layer's top-k
+    of the same weights and batch."""
+    from repro.data import SyntheticCorpus
+    from repro.models import ffn as F
+
+    cfg = _dsv2_tiny()
+    model = build_model(cfg, remat=False)
+    dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=16, global_batch=2)
+    tr = Trainer(model, optim.AdamWConfig(),
+                 TrainerConfig(n_steps=1, ckpt_every=10 ** 9,
+                               ckpt_dir=str(tmp_path), log_every=1), dcfg)
+    tr.run(seed=5, resume=False)
+    (h,) = tr.history
+
+    picks = []
+    route = F._route
+
+    def recording(x, router_w, m):
+        top_p, top_e, aux = route(x, router_w, m)
+        jax.debug.callback(lambda e: picks.append(np.asarray(e)), top_e)
+        return top_p, top_e, aux
+
+    monkeypatch.setattr(F, "_route", recording)
+    batch = SyntheticCorpus(dcfg).batch(0)
+    jax.block_until_ready(model.loss(model.init(jax.random.key(5)), batch))
+    n_moe = cfg.n_layers - cfg.moe.first_dense_layers
+    assert len(picks) == n_moe
+    loads = [np.bincount(e.ravel(), minlength=8)[2:6] for e in picks]
+    assert h["moe_held_pairs"] == sum(int(x.sum()) for x in loads)
+    assert h["moe_max_expert_pairs"] == max(int(x.max()) for x in loads)
+    assert 0 < h["moe_held_pairs"] < n_moe * 2 * 16 * cfg.moe.top_k

@@ -48,8 +48,9 @@ def test_grad_accumulation_invariance(small_model):
     cfg, model = small_model
     params = model.init(jax.random.key(0))
     batch = _batch(cfg, B=8)
-    l1, g1 = jax.jit(make_loss_and_grad(model, accum=1))(params, batch)
-    l4, g4 = jax.jit(make_loss_and_grad(model, accum=4))(params, batch)
+    l1, g1, c1 = jax.jit(make_loss_and_grad(model, accum=1))(params, batch)
+    l4, g4, c4 = jax.jit(make_loss_and_grad(model, accum=4))(params, batch)
+    assert c1 == c4 == {}                   # a dense model counts nothing
     np.testing.assert_allclose(float(l1), float(l4), rtol=2e-5)
     # bf16 forward + different reduction orders: tolerance reflects the
     # grads' own magnitude (~1e-3).  atol also covers the thread-pool
@@ -60,6 +61,25 @@ def test_grad_accumulation_invariance(small_model):
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g4)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-2, atol=1e-3)
+
+
+def test_moe_counters_merge_over_microbatches():
+    """The expert layer's counters of one step, whole or in 4
+    microbatches: the held pairs add up to the whole batch's, and the
+    largest load of a microbatch is at most the whole batch's."""
+    import dataclasses
+    cfg = reduced_config(get_config("deepseek-v2-lite-16b"))
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, n_held=4, first_held=2))
+    model = build_model(cfg, remat=False)
+    params = model.init(jax.random.key(0))
+    batch = _batch(cfg, B=8, S=16)
+    _, _, c1 = jax.jit(make_loss_and_grad(model, accum=1))(params, batch)
+    _, _, c4 = jax.jit(make_loss_and_grad(model, accum=4))(params, batch)
+    assert set(c1) == set(c4) == {"moe_held_pairs", "moe_max_expert_pairs"}
+    assert int(c4["moe_held_pairs"]) == int(c1["moe_held_pairs"]) > 0
+    assert 0 < int(c4["moe_max_expert_pairs"]) \
+        <= int(c1["moe_max_expert_pairs"])
 
 
 def test_grad_clipping():
